@@ -14,7 +14,7 @@ import numpy as np
 
 from repro.core import make_strategy
 from repro.core.sparsify import dense_bytes, message_bytes
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, resolve_interpret
 
 from .common import csv_row, mlp_init
 
@@ -41,7 +41,8 @@ def run(quick: bool = False):
         rows.append(csv_row(
             f"compression/density_{density}", 0.0,
             f"msg_bytes={b};dense_bytes={dense};ratio={dense/b:.0f}x"))
-    # kernel microbench (interpret mode on CPU)
+    # kernel microbench (Pallas interpret mode off the TPU)
+    kind = "interp" if resolve_interpret(None) else "pallas"
     n = 1 << 16 if quick else 1 << 20
     u = jax.random.normal(jax.random.PRNGKey(0), (n,))
     g = jax.random.normal(jax.random.PRNGKey(1), (n,))
@@ -51,13 +52,13 @@ def run(quick: bool = False):
     ref_jit = jax.jit(lambda u, g: ref.samomentum_ref(u, g, thr,
                                                       momentum=0.7, lr=0.1))
     t_ref = _time(lambda: ref_jit(u, g))
-    rows.append(csv_row("kernel/samomentum_interp", t_kern,
+    rows.append(csv_row(f"kernel/samomentum_{kind}", t_kern,
                         f"ref_us={t_ref:.1f};n={n}"))
     k = max(1, n // 100)
     t_hier = _time(lambda: ops.hierarchical_topk(u, k=k, r=32))
     topk_jit = jax.jit(lambda x: jax.lax.top_k(jnp.abs(x), k))
     t_topk = _time(lambda: topk_jit(u))
-    rows.append(csv_row("kernel/block_topk_interp", t_hier,
+    rows.append(csv_row(f"kernel/block_topk_{kind}", t_hier,
                         f"lax_topk_us={t_topk:.1f};k={k}"))
     rows.extend(run_engines(quick=quick))
     rows.extend(run_quantization(quick=quick))

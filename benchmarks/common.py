@@ -45,6 +45,7 @@ def bench_environment() -> dict:
         "python": platform.python_version(),
         "jax": jax.__version__,
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
         "device_count": jax.device_count(),
     }
 

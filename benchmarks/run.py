@@ -21,6 +21,8 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_bandwidth, bench_compression, bench_convergence,
                    bench_lstm, bench_scalability, roofline_table)
     benches = {

@@ -1,6 +1,4 @@
 """repro.core — the paper's contribution: DGS + SAMomentum + async runtime."""
-from repro import compat  # noqa: F401  (jax version backfills, side effects)
-
 from . import (async_sim, baselines, distributed, engine, paramspace,
                samomentum, scan_runner, server, sparsify)
 from .baselines import ASGD, DGS, DGCAsync, DGSPlain, GDAsync, make_strategy

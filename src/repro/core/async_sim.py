@@ -451,7 +451,8 @@ def mesh_batched_server_step_fn(secondary_density, spec: CompressionSpec):
             return (M, t), out
 
         (M, t), outs = jax.lax.scan(body, (sstate.M, sstate.t), xs)
-        sstate = sstate._replace(M=M, t=t, overflow=sstate.overflow + ovf)
+        sstate = ps.keep_on_shards(
+            sstate._replace(M=M, t=t, overflow=sstate.overflow + ovf))
         if dense_down:
             return sstate, outs[0], outs[1]
         return sstate, outs[0], None
@@ -476,7 +477,8 @@ def make_mesh_batched_commit(dense_down: bool):
     if dense_down:
         def commit(sstate, ids, G, M_rows):
             # M_rows: (B, S, width) mesh prefix states; G: (B, total)
-            sstate = sstate._replace(v=sstate.v.at[ids].set(M_rows))
+            sstate = ps.keep_on_shards(
+                sstate._replace(v=sstate.v.at[ids].set(M_rows)))
             return sstate, jnp.sum(G != 0.0, axis=-1)
     else:
         def commit(sstate, ids, G):
@@ -495,8 +497,8 @@ def make_mesh_batched_commit(dense_down: bool):
                 ids[:, None, None],
                 jnp.arange(S, dtype=jnp.int32)[None, :, None],
                 cols].add(rv)[:, :, :-1]
-            return sstate._replace(v=new_v,
-                                   overflow=sstate.overflow + ovf)
+            return ps.keep_on_shards(
+                sstate._replace(v=new_v, overflow=sstate.overflow + ovf))
     return jax.jit(commit, donate_argnums=(0,))
 
 
@@ -702,6 +704,54 @@ class AsyncTrainer:
         _record_run_summary(rec, "serial", hist, up_cost, down_cost,
                             per_up, per_down)
         return final, sstate, hist
+
+    def batched_programs(self, params0, schedule, batch_fn, *,
+                         max_batch: int | None = None, eval_every: int = 0):
+        """Every stage program :meth:`run_batched` dispatches for
+        ``schedule``, as ``(jitted_stage, abstract_args)`` pairs: one per
+        stage per batch size, with one batch's shapes from
+        ``batch_fn(0, worker)``.
+
+        ``jitted_stage.lower(*args).compile()`` builds the executable the
+        run then uses.  Compiled one after another on the first batch of
+        each size, these programs add up to minutes at real widths; a
+        caller can compile them side by side in threads before the run.
+        """
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(tuple(shape), dtype)
+
+        space = ParamSpace.from_tree(params0)
+        client, server, commit, apply_rows, q_up, q_down = \
+            self._batched_stages(space)
+        n = self.n_workers
+        wp = sds((n, space.total), jnp.float32)
+        ws = jax.eval_shape(lambda p: jax.tree.map(
+            lambda s: jnp.broadcast_to(s[None], (n,) + s.shape),
+            self.strategy.init(p)), params0)
+        sstate = jax.eval_shape(lambda p: ps.init(p, n), params0)
+        one = jax.tree.map(jnp.asarray, batch_fn(0, int(schedule[0])))
+        sizes = sorted({len(b) for b in batch_schedule(
+            schedule, max_batch=max_batch, cut_every=eval_every or None)})
+        progs = []
+        for b in sizes:
+            ids, lrs = sds((b,), jnp.int32), sds((b,), jnp.float32)
+            data = jax.tree.map(lambda x: sds((b,) + x.shape, x.dtype), one)
+            progs.append((client, (wp, ws, ids, data, lrs)))
+            msgs = jax.eval_shape(client, *progs[-1][1])[2]
+            if q_up is not None:
+                progs.append((q_up, (msgs,)))
+                msgs = jax.eval_shape(q_up, msgs)
+            progs.append((server, (sstate, msgs, ids)))
+            _, G, M_rows = jax.eval_shape(server, *progs[-1][1])
+            if self.secondary_density is None:
+                progs.append((commit, (sstate, ids, G, M_rows)))
+            else:
+                if q_down is not None:
+                    progs.append((q_down, (G,)))
+                    G = jax.eval_shape(q_down, G)
+                progs.append((commit, (sstate, ids, G)))
+            progs.append((apply_rows, (wp, ids, G)))
+        return progs
 
     def run_batched(
         self,

@@ -48,11 +48,16 @@ class Strategy:
     sparse: bool = False
     engine: str = "exact"
     quantize: str = "none"
+    # blockwise engine: per-block candidates (None = k, the exact mode —
+    # a full per-block sort once k reaches the block size); r << k is the
+    # oversampled production mode
+    block_r: int | None = None
 
     @property
     def spec(self) -> CompressionSpec:
         """The compression-engine spec this strategy selects with."""
-        return CompressionSpec(engine=self.engine, quantize=self.quantize)
+        return CompressionSpec(engine=self.engine, quantize=self.quantize,
+                               block_r=self.block_r)
 
     @property
     def value_bits(self) -> int:

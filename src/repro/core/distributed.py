@@ -409,9 +409,24 @@ def _linear_index(axis_names):
 # `mesh_shards` server stage (DESIGN.md §14)
 # ---------------------------------------------------------------------------
 
+def shard_mesh(n_shards: int):
+    """The ``shards`` mesh the mesh-shard server spreads over: the first
+    ``n_shards`` devices, on an accelerator with at least that many, else
+    ``None`` — forced-host CPU "devices" share the same cores, so the
+    multi-device program would replicate the surrounding stage work S
+    times for zero parallel gain (the single-device layout is
+    bit-identical)."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    if (n_shards < 2 or len(jax.devices()) < n_shards
+            or jax.default_backend() == "cpu"):
+        return None
+    return Mesh(np.asarray(jax.devices()[:n_shards]), ("shards",))
+
+
 def shard_exchange_batch(spec: ShardSpec, indices, values, *,
                          cap: int | None = None,
-                         interpret: bool | None = None,
                          use_mesh: bool | None = None):
     """Route a batch of global-index sparse messages to shard-local slots.
 
@@ -432,10 +447,8 @@ def shard_exchange_batch(spec: ShardSpec, indices, values, *,
     trade slots for a nonzero ``overflow`` count.
 
     ``use_mesh`` picks the path explicitly (tests pin their bit-equality
-    with it); the ``None`` default auto-selects the collective only on a
-    non-CPU backend with >= S devices — forced-host CPU "devices" share
-    the same cores, so the multi-device program would replicate the
-    surrounding stage work S times for zero parallel gain.
+    with it); the ``None`` default takes the collective exactly when
+    :func:`shard_mesh` finds a mesh.
 
     Returns ``(local_idx, vals, overflow)``: ``(B, S, S*cap)`` shard-local
     indices (``-1`` = empty slot) / values, and the scalar int32 count of
@@ -454,8 +467,7 @@ def shard_exchange_batch(spec: ShardSpec, indices, values, *,
     bounds = jnp.asarray(spec.bounds, jnp.int32)
 
     if use_mesh is None:
-        use_mesh = (S > 1 and len(jax.devices()) >= S
-                    and jax.default_backend() != "cpu")
+        use_mesh = shard_mesh(S) is not None
     if use_mesh and S > 1 and len(jax.devices()) >= S:
         # device-mesh leg: each device routes ITS source chunk and the
         # buckets cross the fabric with the native collective
@@ -468,7 +480,7 @@ def shard_exchange_batch(spec: ShardSpec, indices, values, *,
             # (B, 1, kp): this device's source chunk of every message
             ri_c, rv_c, ovf = ops.route_by_shard_batch(
                 idx_c[:, 0], val_c[:, 0], bounds=bounds, n_shards=S,
-                cap=cap, interpret=interpret)        # (B, S_dst, cap)
+                cap=cap)                             # (B, S_dst, cap)
             send_i = jnp.moveaxis(ri_c, 1, 0).reshape(S, B * cap)
             send_v = jnp.moveaxis(rv_c, 1, 0).reshape(S, B * cap)
             recv_i = _all_to_all(send_i, "shards")   # (S_src, B * cap)
@@ -489,7 +501,7 @@ def shard_exchange_batch(spec: ShardSpec, indices, values, *,
     # (src, dst) permutation all_to_all performs
     ri, rv, ovf = ops.route_by_shard_batch(
         idx3.reshape(B * S, kp), val3.reshape(B * S, kp), bounds=bounds,
-        n_shards=S, cap=cap, interpret=interpret)
+        n_shards=S, cap=cap)
     ri = jnp.swapaxes(ri.reshape(B, S, S, cap), 1, 2)
     rv = jnp.swapaxes(rv.reshape(B, S, S, cap), 1, 2)
     return (ri.reshape(B, S, S * cap), rv.reshape(B, S, S * cap),

@@ -27,8 +27,8 @@ down in ``kernels/ref.py``:
                   accumulate/threshold/rescale pass, ``scatter_apply`` for
                   the support repair.  Exact whenever ``block_r >= k``;
                   with ``block_r < k`` it is the production oversampled
-                  approximation.  ``interpret=None`` auto-falls back to
-                  Pallas interpret mode off-TPU.
+                  approximation.  ``interpret=None`` runs the compiled
+                  kernels on a TPU and Pallas interpret mode elsewhere.
 
 ``engine="auto"`` dispatches by tensor size: exact below
 ``sampled_threshold_above`` elements, sampled at or above it — the knob
@@ -141,12 +141,6 @@ def resolve_engine(spec: CompressionSpec, size: int) -> SelectionEngine:
     if name == "auto":
         name = "sampled" if size >= spec.sampled_threshold_above else "exact"
     return get_engine(name, spec)
-
-
-def _interpret(spec: CompressionSpec) -> bool:
-    if spec.interpret is not None:
-        return spec.interpret
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -269,11 +263,11 @@ class BlockwiseEngine:
 
     name = "blockwise"
     block_r: int | None = None
-    interpret: bool = True
+    interpret: bool | None = None   # None: interpret mode only off-TPU
 
     @classmethod
     def from_spec(cls, spec: CompressionSpec):
-        return cls(block_r=spec.block_r, interpret=_interpret(spec))
+        return cls(block_r=spec.block_r, interpret=spec.interpret)
 
     def _plan(self, n: int, k: int) -> int | None:
         """Per-block candidate count ``r`` guaranteeing >= k REAL

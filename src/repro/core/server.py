@@ -304,13 +304,32 @@ def init_mesh_shards(params, n_workers: int, n_shards: int,
         raise ValueError("shard_spec does not cover the parameter arena")
     w = mesh_width(shard_spec)
     S = shard_spec.n_shards
-    return MeshServerState(
+    return keep_on_shards(MeshServerState(
         M=jnp.zeros((S, w), jnp.float32),
         v=jnp.zeros((n_workers, S, w), jnp.float32),
         t=jnp.zeros((), jnp.int32),
         overflow=jnp.zeros((), jnp.int32),
         space=space,
-        spec=shard_spec)
+        spec=shard_spec))
+
+
+def keep_on_shards(state: MeshServerState) -> MeshServerState:
+    """Place each shard's M row and v column on its own device of the
+    ``shards`` mesh (``distributed.shard_mesh``); inside a jitted stage
+    this pins the outputs so no stage gathers the arenas back onto one
+    device.  A no-op where there is no such mesh (CPU, too few devices)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from .distributed import shard_mesh
+
+    mesh = shard_mesh(state.spec.n_shards)
+    if mesh is None:
+        return state
+    return state._replace(
+        M=jax.lax.with_sharding_constraint(
+            state.M, NamedSharding(mesh, P("shards", None))),
+        v=jax.lax.with_sharding_constraint(
+            state.v, NamedSharding(mesh, P(None, "shards", None))))
 
 
 def mesh_split(spec: ShardSpec, x, width: int | None = None) -> jax.Array:

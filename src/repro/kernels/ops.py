@@ -8,8 +8,15 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from . import resolve_interpret
 from .block_topk import BLOCK, GROUP, block_topk_2d
 from .samomentum_kernel import BLOCK_ROWS, LANE, samomentum_fused_2d
+
+
+# Most updates one dense block of the scatter kernel takes in-kernel; the
+# rest of that block's updates spill to an XLA scatter (exact either way).
+# Bounds the bucket operands at n_blocks * SCATTER_CAP slots whatever k is.
+SCATTER_CAP = 256
 
 
 def _pad_to(x, multiple):
@@ -22,7 +29,7 @@ def _pad_to(x, multiple):
 
 @partial(jax.jit, static_argnames=("momentum", "lr", "interpret"))
 def samomentum_fused(u, g, thr, *, momentum: float, lr: float,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Fused SAMomentum over an arbitrary-shape tensor.
 
     Returns (sent_dense, u_new): sent_dense is the thresholded velocity in
@@ -42,7 +49,7 @@ def samomentum_fused(u, g, thr, *, momentum: float, lr: float,
 
 
 @partial(jax.jit, static_argnames=("r", "interpret"))
-def block_topk_candidates(x, *, r: int, interpret: bool = True):
+def block_topk_candidates(x, *, r: int, interpret: bool | None = None):
     """Per-block top-r winners of |x|.  Returns (vals, global_idx), each
     (nb, r); padding elements (|x| = 0 at index >= x.size) may appear only
     when a block is entirely padding."""
@@ -55,7 +62,7 @@ def block_topk_candidates(x, *, r: int, interpret: bool = True):
 
 @partial(jax.jit, static_argnames=("k", "r", "interpret"))
 def hierarchical_topk(x, *, k: int, r: int | None = None,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """Top-k |x| selection via block winners + candidate top-k.
 
     Exact iff r >= k; production callers pass r << k for the approximate
@@ -86,9 +93,7 @@ def scatter_add(dense, indices, values, *, interpret: bool | None = None):
     block loop in Python and lose the very dispatch-count war the arena
     wins.  Duplicate indices accumulate in both paths.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if interpret:
+    if resolve_interpret(interpret):
         return dense.at[indices].add(values.astype(dense.dtype))
     return scatter_apply(dense, indices, values, interpret=False)
 
@@ -99,9 +104,7 @@ def scatter_add_row(dense2d, row, indices, values, *,
     server's ``v`` buffer.  Off-TPU this is a single 2-D XLA scatter (no
     row gather/set round trip); on TPU the row is sliced, run through the
     blocked Pallas kernel, and written back."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if interpret:
+    if resolve_interpret(interpret):
         return dense2d.at[row, indices].add(values.astype(dense2d.dtype))
     new_row = scatter_apply(dense2d[row], indices, values, interpret=False)
     return dense2d.at[row].set(new_row)
@@ -117,30 +120,28 @@ def scatter_add_rows(dense2d, rows, idx2d, vals2d, *,
     disjoint rows, so one fused scatter is bit-equal to any serial order
     of :func:`scatter_add_row` calls.  Off-TPU this is a single 2-D XLA
     scatter; on TPU the rows are gathered, run through the blocked
-    multi-row Pallas kernel (grid over (lane, block)), and written back.
+    Pallas kernel (one grid over every lane's blocks), and written back.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if interpret:
+    if resolve_interpret(interpret):
         return dense2d.at[rows[:, None], idx2d].add(
             vals2d.astype(dense2d.dtype))
     sub = scatter_apply_rows(dense2d[rows], idx2d, vals2d, interpret=False)
     return dense2d.at[rows].set(sub)
 
 
-def _bucket_blocked(n_pad: int, block: int, cap: int, indices, values,
-                    out_dtype):
-    """Bucket flat scatter updates by dense block (sort + rank).
+def _bucket_blocked(nb: int, cap: int, indices, values):
+    """Bucket flat scatter updates by dense block (stable sort + rank).
 
-    Returns ``(vals2d, offs2d, spill)``: the ``(nb, cap)`` kernel inputs
-    (block-local offsets, -1 = padding) and a ``(n_pad,)`` XLA-scatter
-    remainder for updates past ``cap`` in their block (exactness guard).
-    Shared by the flat and multi-row scatter wrappers.
+    Returns ``(vals2d, offs2d, i_s, v_s, ok)``: the ``(nb, cap)`` kernel
+    inputs (block-local offsets, -1 = padding, real slots first in each
+    bucket) and the sorted updates with ``ok`` marking the ones that got a
+    slot; the rest (rank >= ``cap`` in their block) are the spill the
+    wrapper applies with an XLA scatter, so the op stays exact.
     """
-    nb = n_pad // block
+    from .scatter_apply import BLOCK
     k = values.shape[0]
-    block_of = indices // block
-    order = jnp.argsort(block_of)
+    block_of = indices // BLOCK
+    order = jnp.argsort(block_of, stable=True)
     b_s = block_of[order]
     i_s = indices[order]
     v_s = values[order].astype(jnp.float32)
@@ -148,64 +149,56 @@ def _bucket_blocked(n_pad: int, block: int, cap: int, indices, values,
         b_s, b_s, side="left").astype(jnp.int32)
     ok = rank < cap
     slot = jnp.where(ok, b_s * cap + rank, nb * cap)
-    vals2d = jnp.zeros((nb * cap + 1,), jnp.float32).at[slot].add(
+    vals2d = jnp.zeros((nb * cap + 1,), jnp.float32).at[slot].set(
         jnp.where(ok, v_s, 0.0))[:-1].reshape(nb, cap)
     offs2d = jnp.full((nb * cap + 1,), -1, jnp.int32).at[slot].set(
-        jnp.where(ok, i_s % block, -1))[:-1].reshape(nb, cap)
-    spill = jnp.zeros((n_pad,), out_dtype).at[
-        jnp.where(ok, n_pad, i_s)].add(
-        jnp.where(ok, 0.0, v_s).astype(out_dtype), mode="drop")
-    return vals2d, offs2d, spill
+        jnp.where(ok, i_s % BLOCK, -1))[:-1].reshape(nb, cap)
+    return vals2d, offs2d, i_s, v_s, ok
 
 
 @partial(jax.jit, static_argnames=("cap", "interpret"))
 def scatter_apply(dense, indices, values, *, cap: int | None = None,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """dense.at[indices].add(values) via the blocked Pallas kernel.
 
-    The wrapper buckets updates by dense block (sort + rank), pads each
-    bucket to ``cap`` and runs kernels/scatter_apply.py.  Duplicate indices
-    accumulate.  ``cap`` must upper-bound the densest block's update count
-    (defaults to k, always safe).
+    One row of :func:`scatter_apply_rows`.  Duplicate indices accumulate.
     """
-    from .scatter_apply import BLOCK, scatter_apply_blocked
-    shape = dense.shape
-    flat, _ = _pad_to(dense.reshape(-1), BLOCK)
-    nb = flat.shape[0] // BLOCK
-    k = values.shape[0]
-    cap = min(k, cap) if cap else k
-    vals2d, offs2d, spill = _bucket_blocked(
-        flat.shape[0], BLOCK, cap, indices, values, dense.dtype)
-    out = scatter_apply_blocked(flat.reshape(nb, BLOCK),
-                                vals2d, offs2d, interpret=interpret)
-    out = out.reshape(-1) + spill
-    n = dense.size
-    return out[:n].reshape(shape)
+    out = scatter_apply_rows(dense.reshape(1, -1), indices[None],
+                             values[None], cap=cap, interpret=interpret)
+    return out.reshape(dense.shape)
 
 
 @partial(jax.jit, static_argnames=("cap", "interpret"))
 def scatter_apply_rows(dense2d, idx2d, vals2d, *, cap: int | None = None,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """Row-wise ``dense2d[b].at[idx2d[b]].add(vals2d[b])`` via ONE blocked
-    Pallas dispatch over a (row, block) grid.
+    Pallas dispatch.
 
-    The bucketing is the same sort + rank as :func:`scatter_apply`, vmapped
-    over the batch lanes; the kernel then streams every lane's blocks
-    through VMEM in a single pallas_call instead of one dispatch per lane.
+    Each lane's updates are bucketed by dense block (sort + rank, vmapped
+    over the lanes) into at most ``cap`` slots per block (default
+    ``min(k, SCATTER_CAP)``); every lane's blocks then stream through VMEM
+    in a single pallas_call.  Updates past ``cap`` in their block are
+    applied afterwards by an XLA scatter, so the result is exact for any
+    index distribution while the bucket operands stay
+    ``n_rows * n_blocks * cap`` slots.
     """
-    from .scatter_apply import BLOCK, scatter_apply_blocked_rows
+    from .scatter_apply import BLOCK, LANE, ROWS, scatter_apply_blocked
     n_rows, n = dense2d.shape
     pad = (-n) % BLOCK
     flat = jnp.pad(dense2d, ((0, 0), (0, pad))) if pad else dense2d
     nb = flat.shape[1] // BLOCK
     k = vals2d.shape[1]
-    cap = min(k, cap) if cap else k
-    vals3d, offs3d, spill = jax.vmap(
-        lambda i, v: _bucket_blocked(flat.shape[1], BLOCK, cap, i, v,
-                                     dense2d.dtype))(idx2d, vals2d)
-    out = scatter_apply_blocked_rows(flat.reshape(n_rows, nb, BLOCK),
-                                     vals3d, offs3d, interpret=interpret)
-    out = out.reshape(n_rows, -1) + spill
+    cap = max(1, min(k, cap or SCATTER_CAP))
+    v3, o3, i_s, v_s, ok = jax.vmap(
+        lambda i, v: _bucket_blocked(nb, cap, i, v))(idx2d, vals2d)
+    out = scatter_apply_blocked(
+        flat.reshape(n_rows * nb, ROWS, LANE), v3.reshape(-1, cap),
+        o3.reshape(-1, cap), interpret=resolve_interpret(interpret))
+    out = out.reshape(n_rows, nb * BLOCK)
+    # spill: entries with a slot index past the padded row are dropped
+    out = out.at[jnp.arange(n_rows, dtype=jnp.int32)[:, None],
+                 jnp.where(ok, nb * BLOCK, i_s)].add(
+        v_s.astype(out.dtype), mode="drop")
     return out[:, :n]
 
 
@@ -213,8 +206,7 @@ def scatter_apply_rows(dense2d, idx2d, vals2d, *, cap: int | None = None,
 # shard routing — the in-graph half of the alltoallv exchange
 # ---------------------------------------------------------------------------
 
-def route_by_shard(indices, values, *, bounds, n_shards: int, cap: int,
-                   interpret: bool | None = None):
+def route_by_shard(indices, values, *, bounds, n_shards: int, cap: int):
     """Bucket one global-index sparse message into per-shard slots.
 
     ``indices``: ``(k,)`` int32 global arena indices (``-1`` marks padding);
@@ -230,24 +222,23 @@ def route_by_shard(indices, values, *, bounds, n_shards: int, cap: int,
     see ``distributed.shard_exchange_batch``).
 
     The slot math is the same stable sort + rank idiom as
-    :func:`_bucket_blocked`; the value placement funnels through
-    :func:`scatter_add`, so on TPU it is the blocked Pallas scatter and
-    elsewhere a single XLA scatter.
+    :func:`_bucket_blocked`.
     """
     ri, rv, ovf = route_by_shard_batch(indices[None], values[None],
                                        bounds=bounds, n_shards=n_shards,
-                                       cap=cap, interpret=interpret)
+                                       cap=cap)
     return ri[0], rv[0], ovf
 
 
-def route_by_shard_batch(indices, values, *, bounds, n_shards: int, cap: int,
-                         interpret: bool | None = None):
+def route_by_shard_batch(indices, values, *, bounds, n_shards: int, cap: int):
     """Batched :func:`route_by_shard` over ``(N, k)`` chunks with ONE
     scatter dispatch.
 
-    Rather than vmapping the scatter (which would trace N pallas_calls on
-    TPU), every chunk's slots are offset by ``chunk * (S*cap + 1)`` into a
-    single flat buffer — one kernel launch routes the whole batch.
+    Every chunk's slots are offset by ``chunk * (S*cap + 1)`` into a
+    single flat buffer, so one XLA scatter places the whole batch.  Each
+    real entry owns its slot (only the per-chunk dump slot is shared, and
+    it is cut off), so the placement is a ``set``: no accumulation, and
+    nothing for the blocked scatter kernel to do.
     Returns ``(local_idx, vals, overflow)`` shaped ``(N, S, cap)`` /
     ``(N, S, cap)`` / scalar.
     """
@@ -272,9 +263,8 @@ def route_by_shard_batch(indices, values, *, bounds, n_shards: int, cap: int,
     slot = jnp.where(ok, o_s * cap + rank, S * cap)
     local = i_s - bounds[jnp.clip(o_s, 0, S - 1)]
     flat = (slot + jnp.arange(n, dtype=jnp.int32)[:, None] * row_len).reshape(-1)
-    rv = scatter_add(jnp.zeros((n * row_len,), jnp.float32), flat,
-                     jnp.where(ok, v_s, 0.0).reshape(-1),
-                     interpret=interpret)
+    rv = jnp.zeros((n * row_len,), jnp.float32).at[flat].set(
+        jnp.where(ok, v_s, 0.0).reshape(-1))
     rv = rv.reshape(n, row_len)[:, :-1].reshape(n, S, cap)
     ri = jnp.full((n * row_len,), -1, jnp.int32).at[flat].set(
         jnp.where(ok, local, -1).reshape(-1))

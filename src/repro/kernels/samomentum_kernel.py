@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
+
 LANE = 128
 BLOCK_ROWS = 256     # (256, 128) f32 tile = 128 KiB VMEM per operand
 
@@ -37,7 +39,7 @@ def _kernel(thr_ref, u_ref, g_ref, out_ref, unew_ref, *, momentum, lr):
 
 
 def samomentum_fused_2d(u2d, g2d, thr, *, momentum: float, lr: float,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """u2d/g2d: (rows, 128) with rows % BLOCK_ROWS == 0. thr: (1,1) f32."""
     rows = u2d.shape[0]
     assert u2d.shape[1] == LANE and rows % BLOCK_ROWS == 0, u2d.shape
@@ -54,5 +56,5 @@ def samomentum_fused_2d(u2d, g2d, thr, *, momentum: float, lr: float,
         in_specs=[scalar_spec, spec, spec],
         out_specs=[spec, spec],
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(thr.reshape(1, 1).astype(jnp.float32), u2d, g2d)

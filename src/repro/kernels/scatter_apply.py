@@ -2,101 +2,84 @@
 
 Applying a decoded sparse update (``dense.at[idx].add(vals)``) is a random
 scatter: on TPU the efficient form is to pre-bucket the updates by parameter
-block (a cheap sort on the host side of the op), then stream each dense
-block through VMEM exactly once and apply its updates with on-chip dynamic
+block (a sort on the XLA side of the op), then stream each dense block
+through VMEM exactly once and apply its updates with on-chip dynamic row
 stores.  One HBM round-trip for the parameter vector, no atomics (the TPU
-grid is sequential), contiguous DMA for both the parameters and the
-bucketed updates.
+grid is sequential), contiguous DMA for the parameter blocks.
 
-Layout: params viewed as (n_blocks, BLOCK); updates pre-bucketed to
-(n_blocks, CAP) value/offset pairs padded with offset == -1.
+Layout: the dense rows are viewed as ``(n_blocks, ROWS, LANE)`` f32 tiles
+(one block = ``BLOCK`` = ``ROWS * LANE`` elements, tile-aligned); the
+updates arrive pre-bucketed as ``(n_blocks, cap)`` value/offset pairs with
+offset == -1 marking padding.  The bucket rows sit in SMEM, where the
+kernel reads one scalar (offset, value) pair per update and rewrites the
+single ``(1, LANE)`` row it lands in.  Bucket slots are filled in rank
+order, so the first padding slot ends a block's loop.
+
+``cap`` is bounded (``ops.SCATTER_CAP``), not ``k``: a block holding more
+than ``cap`` updates spills the surplus to an XLA scatter in the wrapper,
+so the op stays exact while the bucket operands stay
+``O(n_blocks * cap)`` however large ``k`` grows.
 
 This is the TPU fast path behind ``ops.scatter_add`` — the ONE fused
 scatter the flat-arena runtime (core/paramspace.py) runs per event for
-server receive, ``v_k`` commit, and worker apply.  A whole model's sparse
-update is a single global-index COO over the packed arena, so the kernel
-sees one big bucketed scatter instead of one tiny scatter per tensor.
+server receive, ``v_k`` commit, and worker apply.  The multi-row commit
+runs through the same kernel: every lane's blocks are stacked into one
+``(n_rows * n_blocks, ROWS, LANE)`` grid.
 
 Semantics contract: kernels/ref.py::scatter_accumulate_ref.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-BLOCK = 2048      # dense elements per block (16 x 128 tile)
+from . import resolve_interpret
 
-
-def _kernel(vals_ref, offs_ref, dense_ref, out_ref, *, cap: int):
-    block = dense_ref[...]          # (1, BLOCK)
-    vals = vals_ref[...]            # (1, CAP)
-    offs = offs_ref[...]            # (1, CAP)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
-
-    def body(j, acc):
-        off = offs[0, j]
-        val = vals[0, j]
-        hit = (lanes == off) & (off >= 0)
-        return acc + jnp.where(hit, val, 0.0).astype(acc.dtype)
-
-    out_ref[...] = jax.lax.fori_loop(0, cap, body, block)
+LANE = 128
+ROWS = 64                  # f32 sublane rows per block (8 tiles of 8 x 128)
+BLOCK = ROWS * LANE        # dense elements per block
 
 
-def scatter_apply_blocked(dense2d, vals2d, offs2d, *, interpret: bool = True):
-    """dense2d: (nb, BLOCK); vals2d/offs2d: (nb, CAP) bucketed updates
-    (offset local to the block, -1 = padding).  Returns updated dense2d."""
+def _kernel(vals_ref, offs_ref, dense_ref, out_ref):
+    out_ref[...] = dense_ref[...]
+    cap = offs_ref.shape[2]
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, LANE), 1)
+
+    def cond(j):
+        return jnp.logical_and(j < cap, offs_ref[0, 0, jnp.minimum(j, cap - 1)]
+                               >= 0)
+
+    def body(j):
+        off = offs_ref[0, 0, j]
+        r = off // LANE
+        row = out_ref[0, pl.ds(r, 1), :]
+        out_ref[0, pl.ds(r, 1), :] = jnp.where(
+            lanes == off % LANE, row + vals_ref[0, 0, j], row)
+        return j + 1
+
+    jax.lax.while_loop(cond, body, jnp.int32(0))
+
+
+def scatter_apply_blocked(dense3d, vals2d, offs2d, *,
+                          interpret: bool | None = None):
+    """dense3d: (nb, ROWS, LANE); vals2d/offs2d: (nb, cap) bucketed updates
+    (offset local to the block, -1 = padding, padding only after every
+    real slot of its block).  Returns the updated dense3d."""
     nb, cap = vals2d.shape
-    assert dense2d.shape == (nb, BLOCK), (dense2d.shape, nb)
-    spec_d = pl.BlockSpec((1, BLOCK), lambda i: (i, 0))
-    spec_u = pl.BlockSpec((1, cap), lambda i: (i, 0))
+    assert dense3d.shape == (nb, ROWS, LANE), (dense3d.shape, nb)
+    spec_d = pl.BlockSpec((1, ROWS, LANE), lambda i: (i, 0, 0))
+    # (1, 1, cap) blocks of an (nb, 1, cap) view: the last two block dims
+    # equal the array's, as the TPU block rule asks of SMEM operands too
+    spec_u = pl.BlockSpec((1, 1, cap), lambda i: (i, 0, 0),
+                          memory_space=pltpu.SMEM)
     return pl.pallas_call(
-        functools.partial(_kernel, cap=cap),
+        _kernel,
         grid=(nb,),
         in_specs=[spec_u, spec_u, spec_d],
         out_specs=spec_d,
-        out_shape=jax.ShapeDtypeStruct(dense2d.shape, dense2d.dtype),
-        interpret=interpret,
-    )(vals2d, offs2d, dense2d)
-
-
-def _rows_kernel(vals_ref, offs_ref, dense_ref, out_ref, *, cap: int):
-    block = dense_ref[...]          # (1, 1, BLOCK)
-    vals = vals_ref[...]            # (1, 1, CAP)
-    offs = offs_ref[...]
-    lanes = jax.lax.broadcasted_iota(jnp.int32, block.shape, 2)
-
-    def body(j, acc):
-        off = offs[0, 0, j]
-        val = vals[0, 0, j]
-        hit = (lanes == off) & (off >= 0)
-        return acc + jnp.where(hit, val, 0.0).astype(acc.dtype)
-
-    out_ref[...] = jax.lax.fori_loop(0, cap, body, block)
-
-
-def scatter_apply_blocked_rows(dense3d, vals3d, offs3d, *,
-                               interpret: bool = True):
-    """Multi-row variant for the batched event loop's commit stage.
-
-    dense3d: (n_rows, nb, BLOCK) — one blocked parameter row per batch
-    lane; vals3d/offs3d: (n_rows, nb, CAP) per-lane bucketed updates.  The
-    grid is (n_rows, nb): every lane's every block streams through VMEM
-    exactly once, so a whole commit batch costs the same HBM traffic as
-    one row costs per lane — no per-event dispatch, no atomics (rows are
-    disjoint by construction, the grid is sequential anyway).
-    """
-    n_rows, nb, cap = vals3d.shape
-    assert dense3d.shape == (n_rows, nb, BLOCK), (dense3d.shape, n_rows, nb)
-    spec_d = pl.BlockSpec((1, 1, BLOCK), lambda b, i: (b, i, 0))
-    spec_u = pl.BlockSpec((1, 1, cap), lambda b, i: (b, i, 0))
-    return pl.pallas_call(
-        functools.partial(_rows_kernel, cap=cap),
-        grid=(n_rows, nb),
-        in_specs=[spec_u, spec_u, spec_d],
-        out_specs=spec_d,
         out_shape=jax.ShapeDtypeStruct(dense3d.shape, dense3d.dtype),
-        interpret=interpret,
-    )(vals3d, offs3d, dense3d)
+        interpret=resolve_interpret(interpret),
+    )(vals2d.astype(jnp.float32).reshape(nb, 1, cap),
+      offs2d.reshape(nb, 1, cap), dense3d)

@@ -12,22 +12,20 @@ arena message spans.  The message values can be (and in the batched
 runtime are) views into the flat parameter arena: nothing here copies
 them before the program runs.
 
-Scale arithmetic is ``sparsify.quantize_parts`` VERBATIM (the same jitted
-sub-program per segment), which is what makes the packed frames bit-equal
-to the legacy per-segment encoder; the Pallas kernels recompute the
-elementwise code/dequantize ops (round/clip/sign/multiply) from the
-broadcast scales — elementwise IEEE ops on identical inputs, so the TPU
-path is bit-equal by construction too.
+Scale arithmetic, codes and dequantized values are
+``sparsify.quantize_parts`` VERBATIM (the same jitted sub-program per
+segment), which is what makes the packed frames bit-equal to the legacy
+per-segment encoder, on every backend.  The one Pallas kernel packs tern
+sign codes, four 2-bit two's-complement codes per byte, little-end first
+(the codec's ``_pack_tern`` order): the wrapper splits the codes into four
+``(m, LANE)`` int32 planes and the kernel ORs them into ``(m, LANE)``
+bytes on whole tiles.  bf16 and int8 need no kernel: their codes are one
+XLA convert or round/clip away from ``quantize_parts``.
 
-Layout convention matches kernels/ops.py: flat vectors pad to
-``(ROWS, LANE)`` f32 tiles; the tern packer consumes ``(m, 4*LANE)`` sign
-codes and emits ``(m, LANE)`` bytes — four 2-bit two's-complement codes
-per byte, little-end first, the codec's ``_pack_tern`` order.
-
-Off-TPU the public entry point uses the identical-arithmetic XLA ops
-(interpret-mode Pallas would serialize the grid loop in Python — the
-repo-wide pitfall); the Pallas path compiles on TPU and is exercised in
-tests via ``interpret=True``.
+Off-TPU the tern packer is the identical-arithmetic XLA op (interpret-mode
+Pallas would serialize the grid loop in Python — the repo-wide pitfall);
+the Pallas path compiles on TPU and is exercised in tests via
+``interpret=True``.
 """
 from __future__ import annotations
 
@@ -44,85 +42,36 @@ ROWS = 8       # f32 tile rows per grid step
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernels (TPU fast path; interpret=True in tests)
+# Pallas tern packer (TPU fast path; interpret=True in tests)
 # ---------------------------------------------------------------------------
 
-def _bf16_kernel(x_ref, code_ref, dq_ref):
-    b = x_ref[...].astype(jnp.bfloat16)
-    code_ref[...] = jax.lax.bitcast_convert_type(b, jnp.uint16)
-    dq_ref[...] = b.astype(jnp.float32)
-
-
-def _int8_kernel(x_ref, s_ref, code_ref, dq_ref):
-    x = x_ref[...]
-    s = s_ref[...]
-    q = jnp.clip(jnp.round(x / s), -127, 127)
-    code_ref[...] = q.astype(jnp.int8)
-    dq_ref[...] = (q * s).astype(jnp.float32)
-
-
-def _tern_kernel(x_ref, s_ref, code_ref, dq_ref):
-    s = jnp.sign(x_ref[...])
-    code_ref[...] = s.astype(jnp.int8)
-    dq_ref[...] = (s * s_ref[...]).astype(jnp.float32)
-
-
 def _tern_pack_kernel(c_ref, o_ref):
-    # (1, 4*LANE) sign codes -> (1, LANE) bytes; byte t packs codes
-    # 4t..4t+3 as little-end 2-bit two's-complement fields
-    u = (c_ref[...].astype(jnp.int32) & 3).reshape(LANE, 4)
-    o_ref[...] = (u[:, 0] | (u[:, 1] << 2) | (u[:, 2] << 4)
-                  | (u[:, 3] << 6)).astype(jnp.uint8).reshape(1, LANE)
-
-
-def _tiles(x, fill=0.0):
-    """Pad a flat vector to full (ROWS, LANE) f32 tiles -> (nr, LANE)."""
-    n = x.shape[0]
-    pad = (-n) % (ROWS * LANE)
-    if pad:
-        x = jnp.pad(x, (0, pad), constant_values=fill)
-    return x.reshape(-1, LANE)
-
-
-def _codes_pallas(values, scale_vec, mode: str, interpret: bool):
-    """(codes, dq) over the padded value tiles, one pallas_call."""
-    x2d = _tiles(values)
-    nb = x2d.shape[0] // ROWS
-    spec = pl.BlockSpec((ROWS, LANE), lambda i: (i, 0))
-    code_dtype = jnp.uint16 if mode == "bf16" else jnp.int8
-    out_shape = (jax.ShapeDtypeStruct(x2d.shape, code_dtype),
-                 jax.ShapeDtypeStruct(x2d.shape, jnp.float32))
-    if mode == "bf16":
-        codes, dq = pl.pallas_call(
-            _bf16_kernel, grid=(nb,), in_specs=[spec],
-            out_specs=(spec, spec), out_shape=out_shape,
-            interpret=interpret)(x2d)
-    else:
-        kernel = _int8_kernel if mode == "int8" else _tern_kernel
-        s2d = _tiles(scale_vec, fill=1.0)   # pad with 1s: no 0-divides
-        codes, dq = pl.pallas_call(
-            kernel, grid=(nb,), in_specs=[spec, spec],
-            out_specs=(spec, spec), out_shape=out_shape,
-            interpret=interpret)(x2d, s2d)
-    k = values.shape[0]
-    return codes.reshape(-1)[:k], dq.reshape(-1)[:k]
+    # (4, ROWS, LANE) sign-code planes -> (ROWS, LANE) bytes (as int32):
+    # plane q holds code 4t+q of byte t, packed as the q-th little-end
+    # 2-bit two's-complement field
+    u = c_ref[...] & 3
+    o_ref[...] = u[0] | (u[1] << 2) | (u[2] << 4) | (u[3] << 6)
 
 
 def _pack_tern_pallas(codes, interpret: bool):
-    """int8 sign codes (k,) -> (ceil(k/4),) packed bytes via the kernel."""
+    """int32 sign codes (k,) -> (ceil(k/4),) packed bytes via the kernel.
+
+    The wrapper splits the codes into four planes (plane q = codes
+    q, q+4, q+8, ...), so the kernel packs with elementwise ops on whole
+    (ROWS, LANE) tiles and never reshapes inside."""
     k = codes.shape[0]
-    pad = (-k) % (4 * LANE)
+    pad = (-k) % (4 * ROWS * LANE)
     if pad:
         codes = jnp.pad(codes, (0, pad))
-    c2d = codes.reshape(-1, 4 * LANE)
-    m = c2d.shape[0]
+    planes = codes.reshape(-1, 4).T.reshape(4, -1, LANE)
+    m = planes.shape[1]
     packed = pl.pallas_call(
-        _tern_pack_kernel, grid=(m,),
-        in_specs=[pl.BlockSpec((1, 4 * LANE), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, LANE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, LANE), jnp.uint8),
-        interpret=interpret)(c2d)
-    return packed.reshape(-1)[: (k + 3) // 4]
+        _tern_pack_kernel, grid=(m // ROWS,),
+        in_specs=[pl.BlockSpec((4, ROWS, LANE), lambda i: (0, i, 0))],
+        out_specs=pl.BlockSpec((ROWS, LANE), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, LANE), jnp.int32),
+        interpret=interpret)(planes)
+    return packed.reshape(-1)[: (k + 3) // 4].astype(jnp.uint8)
 
 
 # ---------------------------------------------------------------------------
@@ -162,9 +111,10 @@ def quantize_pack(values, *, mode: str, seg: tuple,
       * ``shipped``    — (k,) f32 dequantized values: bit-for-bit what the
         decoder on the far side reconstructs (== ``quantize_segments``).
 
-    ``pallas=None`` routes by backend (Pallas kernels on TPU, plain XLA
-    elsewhere — same convention as ``ops.scatter_add``); tests force the
-    kernel path with ``pallas=True, interpret=True``.
+    ``pallas=None`` routes the tern packer by backend (the Pallas kernel on
+    TPU, plain XLA elsewhere — same convention as ``ops.scatter_add``);
+    tests force the kernel with ``pallas=True, interpret=True``.  bf16 and
+    int8 take the XLA ops everywhere.
     """
     if pallas is None:
         pallas = jax.default_backend() == "tpu"
@@ -174,23 +124,14 @@ def quantize_pack(values, *, mode: str, seg: tuple,
     if mode == "none":
         return values, jnp.zeros((len(seg),), jnp.float32), values
 
-    # per-segment scale reductions: quantize_parts verbatim (XLA either
-    # way — the reduction order must match the legacy encoder exactly)
+    # quantize_parts verbatim, segment by segment (the reduction order
+    # must match the legacy encoder exactly)
     parts, off = [], 0
     for s in seg:
         parts.append(quantize_parts(
             jax.lax.slice_in_dim(values, off, off + s), mode))
         off += s
     scales = jnp.stack([p[1] for p in parts])
-
-    if pallas and mode != "none":
-        scale_vec = jnp.repeat(scales, jnp.asarray(seg),
-                               total_repeat_length=k)
-        codes, dq = _codes_pallas(values, scale_vec, mode,
-                                  interpret=interpret)
-        if mode == "tern":
-            codes = _pack_tern_pallas(codes, interpret=interpret)
-        return codes, scales, dq
 
     codes = (parts[0][0] if len(parts) == 1
              else jnp.concatenate([p[0] for p in parts]))
@@ -199,7 +140,9 @@ def quantize_pack(values, *, mode: str, seg: tuple,
     if mode == "bf16":
         codes = jax.lax.bitcast_convert_type(codes, jnp.uint16)
     elif mode == "tern":
-        codes = _pack_tern_xla(codes)
+        codes = (_pack_tern_pallas(codes.astype(jnp.int32),
+                                   interpret=interpret)
+                 if pallas else _pack_tern_xla(codes))
     return codes, scales, dq
 
 

@@ -1,5 +1,3 @@
-from repro import compat  # noqa: F401  (jax version backfills, side effects)
-
 from . import mesh, roofline, sharding, steps
 
 __all__ = ["cluster", "mesh", "roofline", "sharding", "steps"]

@@ -29,6 +29,12 @@ per shard when available (``XLA_FLAGS=--xla_force_host_platform_device_``
 ``count=S`` on CPU); otherwise the bit-identical single-device fallback.
 Mutually exclusive with ``--shards``.
 
+One process per chip: the coordinator may hold the accelerator, so every
+child this launcher spawns (clients here, trainers and replicas in
+``launch/serve.py``) starts with ``JAX_PLATFORMS=cpu``.  The children stand
+for the federated devices on the far side of the wire; a child that
+reached for the chip the coordinator holds would fail or hang.
+
 ``--smoke`` is the CI guard for the multiprocess path: 2 clients, a few
 int8-quantized rounds, asserts the loss dropped, and exits nonzero on any
 hang (every stage is timeout-bounded).  With ``--shards S`` (or
@@ -40,6 +46,7 @@ from __future__ import annotations
 
 import argparse
 import atexit
+import os
 import signal
 import subprocess
 import sys
@@ -64,8 +71,9 @@ _CHILDREN: list[subprocess.Popen] = []
 
 
 def spawn(cmd) -> subprocess.Popen:
-    """``Popen`` tracked for reaping by :func:`reap_children`."""
-    proc = subprocess.Popen(cmd)
+    """``Popen`` tracked for reaping by :func:`reap_children`.  The child
+    runs JAX on the CPU: the spawning process may hold the chip."""
+    proc = subprocess.Popen(cmd, env={**os.environ, "JAX_PLATFORMS": "cpu"})
     _CHILDREN.append(proc)
     return proc
 
@@ -456,6 +464,8 @@ def main(argv=None):
 
     if args.role == "client":
         return run_client(args)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     return run_coordinator(args, spawn_clients=args.role == "auto")
 
 

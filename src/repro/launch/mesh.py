@@ -5,27 +5,28 @@ Target hardware: TPU v5e, 256 chips per pod (16x16), optionally 2 pods.
 this module never touches jax device state; the dry-run sets
 ``--xla_force_host_platform_device_count=512`` before any jax import.
 
-``axis_types`` (all-Auto, so GSPMD owns the "model" axis) is only passed on
-jax versions that have it — jax 0.4.x has neither the kwarg nor
-``jax.sharding.AxisType`` (see repro.compat).
+Every axis is ``AxisType.Auto``, so GSPMD owns the "model" axis outside the
+exchange's manual ``shard_map`` over the data axes.
 """
 from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh_kwargs
+
+def _auto(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **make_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests use small ones, e.g. (2, 2))."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **make_mesh_kwargs(len(axes)))
+                         axis_types=_auto(len(axes)))
 
 
 def data_axis_names(mesh) -> tuple[str, ...]:

@@ -23,6 +23,11 @@ Three roles:
 
 * ``--role decode`` — the standalone mesh decode demo (prefill + decode
   loop on a host mesh; no cluster).
+
+The fleet coordinator is the one process that may hold the chip: trainer
+and replica children are spawned through ``launch/cluster.spawn`` with
+``JAX_PLATFORMS=cpu``, standing for the devices on the far side of the
+wire.
 """
 from __future__ import annotations
 
@@ -337,7 +342,9 @@ def main(argv=None):
         return run_replica(args)
     if args.role == "decode":
         return run_decode(args)
+    from repro.compile_cache import enable_compile_cache
     from repro.launch.cluster import install_reaper
+    enable_compile_cache()
     install_reaper()
     return run_fleet(args)
 

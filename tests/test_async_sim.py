@@ -267,3 +267,61 @@ def test_metrics_do_not_change_bits(name, kw, sec):
         a.pop("update_mag_hist"), b.pop("update_mag_hist")
         assert a == b
         assert sum(other["update_mag_hist"]["counts"]) == n_events
+
+
+@pytest.mark.parametrize("name,kw,sec", [
+    ("dgs", dict(density=0.1, quantize="int8"), 0.1),
+    ("asgd", dict(), None),
+])
+def test_batched_programs_prebuild_every_stage(name, kw, sec):
+    """Once ``batched_programs`` are compiled, the run compiles no stage
+    program of its own, and its result is bit-equal to a run that
+    compiled lazily."""
+    import logging
+
+    import jax
+    from repro.core import make_strategy
+    from repro.core.engine import CompressionSpec
+
+    params0, grad_fn, batch_fn = _parity_problem()
+    sched = async_sim.make_schedule(5, 40, seed=3, hetero=0.9)
+
+    def trainer():
+        return async_sim.AsyncTrainer(
+            make_strategy(name, **kw), grad_fn, 5, lr=0.05,
+            secondary_density=sec,
+            secondary_spec=CompressionSpec(engine="exact", quantize="bf16"))
+
+    tr = trainer()
+    n_sizes = len({len(b) for b in async_sim.batch_schedule(
+        sched, max_batch=4)})
+    progs = tr.batched_programs(params0, sched, batch_fn, max_batch=4)
+    assert len(progs) >= 4 * n_sizes
+    for fn, args in progs:
+        fn.lower(*args).compile()
+
+    stage_names = ("jit(run)", "jit(server_batch)", "jit(commit)",
+                   "jit(apply_rows)", "jit(<lambda>)")
+    seen = []
+
+    class Catch(logging.Handler):
+        def emit(self, record):
+            msg = record.getMessage()
+            if "Finished XLA compilation" in msg:
+                seen.append(msg)
+
+    handler = Catch()
+    logger = logging.getLogger("jax")
+    logger.addHandler(handler)
+    try:
+        with jax.log_compiles():
+            f1, _, h1 = tr.run_batched(params0, sched, batch_fn,
+                                       max_batch=4)
+    finally:
+        logger.removeHandler(handler)
+    assert not [m for m in seen if any(s in m for s in stage_names)], seen
+    f2, _, h2 = trainer().run_batched(params0, sched, batch_fn, max_batch=4)
+    np.testing.assert_array_equal(h1.losses, h2.losses)
+    assert (h1.up_bytes, h1.down_bytes) == (h2.up_bytes, h2.down_bytes)
+    for a, b in zip(jax.tree.leaves(f1), jax.tree.leaves(f2)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

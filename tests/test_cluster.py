@@ -422,3 +422,76 @@ def test_mesh_serving_not_implemented():
         run_inprocess(strat, grad_fn, params0, batch_fn,
                       schedule=np.zeros(4, np.int64), lr=0.03,
                       mesh_shards=2, n_replicas=1)
+
+
+def test_mesh_shards_spread_over_devices_bitwise():
+    """With a ``shards`` mesh (here 4 forced host devices, the mesh rule
+    patched to accept them), every shard's M row and v column sits on its
+    own device before and after the server stages, the stage carries the
+    all_to_all, and the run still equals the flat single server bit for
+    bit.  Runs in a subprocess so the device count cannot leak."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    import repro
+
+    code = """
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import Mesh
+from repro.core import async_sim, distributed, make_strategy
+from repro.core import server as ps
+from repro.core.engine import CompressionSpec
+from repro.core.sparsify import SparseLeaf
+from repro.cluster.runner import run_inprocess
+distributed.shard_mesh = lambda S: Mesh(np.asarray(jax.devices()[:S]),
+                                        ("shards",))
+key = jax.random.PRNGKey(0)
+Wt = jax.random.normal(key, (6, 4))
+def grad_fn(p, batch):
+    x, y = batch
+    loss = lambda q: jnp.mean((x @ q["w"] + q["b"] - y) ** 2)
+    return jax.value_and_grad(loss)(p)
+def batch_fn(e, k):
+    x = jax.random.normal(jax.random.PRNGKey(int(e) * 131 + int(k) + 1),
+                          (8, 6))
+    return x, x @ Wt
+params0 = {"w": jnp.zeros((6, 4)), "b": jnp.zeros((4,))}
+strat = make_strategy("dgs", density=0.2, momentum=0.7, quantize="int8")
+kw = dict(schedule=async_sim.make_schedule(3, 24, seed=7, hetero=0.9),
+          lr=0.03, secondary_density=0.2,
+          secondary_spec=CompressionSpec(engine="exact"))
+f1, h1 = run_inprocess(strat, grad_fn, params0, batch_fn, **kw)
+fM, hM = run_inprocess(strat, grad_fn, params0, batch_fn, mesh_shards=4,
+                       **kw)
+np.testing.assert_array_equal(h1.losses, hM.losses)
+for a, b in zip(jax.tree.leaves(f1), jax.tree.leaves(fM)):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+assert (h1.up_bytes, h1.down_bytes) == (hM.up_bytes, hM.down_bytes)
+def spread(st):
+    for arr, axis in ((st.M, 0), (st.v, 1)):
+        shards = arr.addressable_shards
+        assert len({s.device for s in shards}) == 4, shards
+        assert all(s.data.shape[axis] == 1 for s in shards)
+st = ps.init_mesh_shards(params0, 3, 4)
+spread(st)
+stage = async_sim.make_mesh_batched_server_step(
+    0.2, CompressionSpec(engine="exact"))
+msgs = SparseLeaf(values=jnp.ones((2, 5)),
+                  indices=jnp.asarray([[0, 3, 9, 17, 27]] * 2, jnp.int32),
+                  size=28)
+ids = jnp.asarray([0, 1], jnp.int32)
+assert "all-to-all" in stage.lower(st, msgs, ids).compile().as_text()
+st, _, _ = stage(st, msgs, ids)
+spread(st)
+print("MESH_SPREAD_OK")
+"""
+    root = pathlib.Path(next(iter(repro.__path__))).parent
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=str(root))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert "MESH_SPREAD_OK" in out.stdout

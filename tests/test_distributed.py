@@ -126,16 +126,6 @@ def test_mesh_allgather_auto_engine_dispatch_matches_exact():
     assert "AUTO_DISPATCH_MATCH" in out
 
 
-def _supports_partial_auto() -> bool:
-    from repro.compat import supports_partial_auto_shard_map
-    return supports_partial_auto_shard_map()
-
-
-@pytest.mark.skipif(
-    not _supports_partial_auto(),
-    reason="partial-auto shard_map (manual data + auto model axis of size "
-           ">1) crashes the XLA SPMD partitioner on jax 0.4.x; "
-           "model_par=1 paths are covered by the other mesh tests")
 def test_mesh_train_step_loss_decreases():
     """End-to-end: reduced arch trains on a (4 data x 2 model) mesh with the
     sparse exchange and the loss goes down."""

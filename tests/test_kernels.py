@@ -162,3 +162,49 @@ def test_scatter_apply_rows_cap_spill():
                         for b in range(n_rows)])
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=1e-6)
+
+
+def test_scatter_apply_default_cap_spills_exactly():
+    """More updates in one block than the kernel's bounded bucket
+    (SCATTER_CAP): the surplus spills to the XLA scatter, and the result
+    still equals the plain scatter bit for bit (unique indices)."""
+    from repro.kernels.scatter_apply import BLOCK
+    n = 3 * BLOCK + 77
+    k = ops.SCATTER_CAP * 2 + 9
+    rng = np.random.default_rng(3)
+    idx = np.concatenate([rng.choice(BLOCK, k - 9, replace=False),
+                          rng.choice(np.arange(2 * BLOCK, n), 9,
+                                     replace=False)]).astype(np.int32)
+    dense = jnp.asarray(rng.normal(size=n).astype(np.float32))
+    vals = jnp.asarray(rng.normal(size=k).astype(np.float32))
+    out = ops.scatter_apply(dense, jnp.asarray(idx), vals, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(dense.at[idx].add(vals)))
+
+
+def test_scatter_apply_keeps_negative_zero():
+    """Lanes a block's updates do not hit keep their exact bits (the
+    kernel selects, it never adds 0.0 to them: -0.0 stays -0.0)."""
+    dense = jnp.full((300,), -0.0, jnp.float32)
+    out = ops.scatter_apply(dense, jnp.asarray([7], jnp.int32),
+                            jnp.asarray([1.5], jnp.float32), interpret=True)
+    assert np.signbit(np.asarray(out)[[0, 8, 299]]).all()
+    assert float(out[7]) == 1.5
+
+
+def test_block_topk_full_block_sweep():
+    """r == BLOCK: one loop of BLOCK selection sweeps sorts every block
+    by magnitude, matching the reference exactly."""
+    x = jax.random.normal(jax.random.PRNGKey(5), (2 * 1024 + 300,))
+    cv, ci = ops.block_topk_candidates(x, r=1024, interpret=True)
+    rv, ri = ref.block_topk_ref(x, block=1024, r=1024)
+    nb = rv.shape[0]
+    np.testing.assert_array_equal(np.asarray(cv[:nb]), np.asarray(rv))
+    np.testing.assert_array_equal(np.asarray(ci[:nb]), np.asarray(ri))
+
+
+def test_interpret_default_follows_backend():
+    from repro.kernels import resolve_interpret
+    assert resolve_interpret(None) == (jax.default_backend() != "tpu")
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False

@@ -1,4 +1,6 @@
 """Launch-layer units: sharding rules, roofline extraction, shapes."""
+import os
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -107,3 +109,31 @@ class TestRoofline:
         n_active_implied = f / (6 * 4096 * 256)
         # ~22B active for the 235B model
         assert 1.5e10 < n_active_implied < 3.5e10
+
+
+class TestTrainLauncher:
+    def test_smoke_is_a_real_switch(self):
+        from repro.launch import train
+        assert train.parse_args([]).smoke is True
+        args = train.parse_args(["--no-smoke"])
+        assert args.smoke is False and args.devices is None
+
+    def test_host_devices_forced_only_for_cpu(self, monkeypatch):
+        from repro.launch import train
+        # setenv first, so teardown restores whatever was there before
+        monkeypatch.setenv("XLA_FLAGS", "")
+        monkeypatch.delenv("XLA_FLAGS")
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        train.force_host_devices(None)
+        assert "XLA_FLAGS" not in os.environ
+        train.force_host_devices(3)
+        env = os.environ
+        assert env["JAX_PLATFORMS"] == "cpu"
+        assert env["XLA_FLAGS"] == "--xla_force_host_platform_device_count=3"
+
+    def test_compile_cache_defers_to_env(self, monkeypatch, tmp_path):
+        from repro.compile_cache import enable_compile_cache
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before

@@ -253,14 +253,23 @@ class TestPackFromArena:
         np.testing.assert_array_equal(np.asarray(dec.values),
                                       np.asarray(ship_fused.values))
 
+    @pytest.mark.parametrize("ties", (False, True))
     @pytest.mark.parametrize("mode", ("bf16", "int8", "tern"))
-    def test_quantize_pack_pallas_interpret_matches_xla(self, mode):
+    def test_quantize_pack_pallas_interpret_matches_xla(self, mode, ties):
         from repro.kernels import wire_pack
 
         seg = (100, 30, 126)
         k = sum(seg)
         rng = np.random.default_rng(11)
-        values = jnp.asarray(rng.normal(size=k).astype(np.float32))
+        values = rng.normal(size=k).astype(np.float32)
+        if ties:
+            # low 16 bits exactly half a bf16 ulp: the round-to-nearest-
+            # even cases, with odd and even kept bits, plus the largest
+            # finite f32 (rounds up to inf)
+            bits = values.view(np.uint32) & np.uint32(0xFFFF0000)
+            values = (bits | np.uint32(0x8000)).view(np.float32)
+            values[-1] = np.finfo(np.float32).max
+        values = jnp.asarray(values)
         codes_x, scales_x, dq_x = wire_pack.quantize_pack(
             values, mode=mode, seg=seg, pallas=False)
         codes_p, scales_p, dq_p = wire_pack.quantize_pack(
