@@ -59,12 +59,14 @@ from __future__ import annotations
 import struct
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import ml_dtypes
 import numpy as np
 
 from repro.core.sparsify import (SparseLeaf, quantize_parts as
                                  _quantize_parts, quantize_segments)
+from repro.telemetry.trace import LAYER
 
 # message types
 HELLO, WELCOME, UP, DOWN, SKIP, BYE = range(6)
@@ -141,8 +143,9 @@ def quantize_message(msg, mode: str, seg=None):
         return msg
     if seg is None:
         seg = (msg.k,)
-    return SparseLeaf(values=quantize_segments(msg.values, mode, seg),
-                      indices=msg.indices, size=msg.size)
+    with jax.named_scope(LAYER.quantize):
+        values = quantize_segments(msg.values, mode, seg)
+    return SparseLeaf(values=values, indices=msg.indices, size=msg.size)
 
 
 # ---------------------------------------------------------------------------

@@ -53,6 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.telemetry.trace import LAYER
+
 from . import engine as engine_lib
 from . import server as ps
 from .baselines import Strategy, msgd_step
@@ -227,8 +229,10 @@ def client_step_fn(strategy: Strategy, grad_fn, space: ParamSpace):
     strategy = strip_quantize(strategy)
 
     def client_step(theta, wstrat, batch, lr):
-        loss, grads = grad_fn(space.unpack(theta), batch)
-        wstrat, msg = strategy.step(wstrat, grads, lr)
+        with jax.named_scope(LAYER.grad):
+            loss, grads = grad_fn(space.unpack(theta), batch)
+        with jax.named_scope(LAYER.strategy):
+            wstrat, msg = strategy.step(wstrat, grads, lr)
         return wstrat, loss, msg
 
     return client_step
@@ -303,9 +307,13 @@ def make_batched_client_step(strategy: Strategy, grad_fn, space: ParamSpace):
     dense_msg = not strategy.sparse
 
     def run(wp, ws, ids, batches, lrs):
-        st = jax.tree.map(lambda s: s[ids], ws)
-        st2, losses, msgs = vstep(wp[ids], st, batches, lrs)
-        ws = jax.tree.map(lambda s, r: s.at[ids].set(r), ws, st2)
+        with jax.named_scope(LAYER.strategy):
+            st = jax.tree.map(lambda s: s[ids], ws)
+        with jax.named_scope(LAYER.grad):
+            theta = wp[ids]
+        st2, losses, msgs = vstep(theta, st, batches, lrs)
+        with jax.named_scope(LAYER.strategy):
+            ws = jax.tree.map(lambda s, r: s.at[ids].set(r), ws, st2)
         nnz = (jnp.sum(msgs != 0.0, axis=-1) if dense_msg
                else jnp.zeros(ids.shape, jnp.int32))
         return ws, losses, msgs, nnz
@@ -339,14 +347,13 @@ def batched_server_step_fn(secondary_density, spec: CompressionSpec):
     ``M_rows`` the per-event prefix M stack when the downward message is
     dense (``secondary_density is None`` — the commit's ``v_k <- M`` snap
     must use M *as of that event*, see ``server.send_commit_rows``), else
-    ``None``.
+    ``None``.  Everything but the secondary selection runs under the
+    receive layer's scope.
     """
     dense_down = secondary_density is None
     spec_raw = dataclasses.replace(spec, quantize="none")
 
     def server_batch(sstate, msgs, ids):
-        v_rows = sstate.v[ids]
-
         def body(carry, x):
             M, t = carry
             msg, v_k = x
@@ -359,8 +366,10 @@ def batched_server_step_fn(secondary_density, spec: CompressionSpec):
                     diff, st.space.ks(secondary_density), spec_raw),)
             return (st.M, st.t), out
 
-        (M, t), outs = jax.lax.scan(body, (sstate.M, sstate.t),
-                                    (msgs, v_rows))
+        with jax.named_scope(LAYER.receive):
+            v_rows = sstate.v[ids]
+            (M, t), outs = jax.lax.scan(body, (sstate.M, sstate.t),
+                                        (msgs, v_rows))
         sstate = sstate._replace(M=M, t=t)
         if dense_down:
             return sstate, outs[0], outs[1]
@@ -509,7 +518,8 @@ def make_batched_apply():
     vapply = jax.vmap(ps.apply_update)
 
     def apply_rows(wp, ids, G):
-        return wp.at[ids].set(vapply(wp[ids], G))
+        with jax.named_scope(LAYER.apply):
+            return wp.at[ids].set(vapply(wp[ids], G))
 
     return jax.jit(apply_rows, donate_argnums=(0,))
 
@@ -599,22 +609,18 @@ class AsyncTrainer:
         lr_fn: Callable[[int], float] | None = None,
         eval_fn: Callable | None = None,
         eval_every: int = 0,
-        recorder=None,
         metrics: bool = False,
     ):
         """Run the full schedule.  batch_fn(event_idx, worker_id) -> batch.
 
-        ``recorder`` (a :class:`repro.telemetry.Recorder`) traces per-event
-        host spans + run events; ``metrics=True`` folds every event into an
-        on-device :class:`~repro.telemetry.metrics.MetricsState` (drained
-        into ``History.metrics`` at the end).  Both default OFF, leaving
-        this loop byte-identical to the untelemetered path.
+        ``metrics=True`` folds every event into an on-device
+        :class:`~repro.telemetry.metrics.MetricsState` (drained into
+        ``History.metrics`` at the end).  It defaults OFF, leaving this
+        loop byte-identical to the untelemetered path.
         """
         from repro.cluster import wire  # codec quantizer + byte accounting
-        from repro import telemetry
         from repro.telemetry import metrics as metrics_lib
 
-        rec = recorder if recorder is not None else telemetry.NULL
         space = ParamSpace.from_tree(params0)
         sstate, workers = self.init(params0)
         client_step, server_step, commit, apply_G = \
@@ -646,20 +652,14 @@ class AsyncTrainer:
         for e, k in enumerate(schedule):
             k = int(k)
             lr = self.lr if lr_fn is None else float(lr_fn(e))
-            with rec.span("sim/batch_build", worker=k):
-                batch = batch_fn(e, k)
-            with rec.span("sim/client_step", worker=k):
-                wst, loss, msg = client_step(
-                    workers[k]["theta"], workers[k]["strat"], batch, lr)
-            with rec.span("sim/wire_quantize"):
-                msg = wire.quantize_message(msg, up_mode, seg=up_seg)
-            with rec.span("sim/server_step"):
-                sstate, G = server_step(sstate, msg, jnp.int32(k))
-                G = wire.quantize_message(G, down_mode, seg=down_seg)
-            with rec.span("sim/commit"):
-                sstate = commit(sstate, jnp.int32(k), G)
-            with rec.span("sim/apply"):
-                workers[k]["theta"] = apply_G(workers[k]["theta"], G)
+            batch = batch_fn(e, k)
+            wst, loss, msg = client_step(
+                workers[k]["theta"], workers[k]["strat"], batch, lr)
+            msg = wire.quantize_message(msg, up_mode, seg=up_seg)
+            sstate, G = server_step(sstate, msg, jnp.int32(k))
+            G = wire.quantize_message(G, down_mode, seg=down_seg)
+            sstate = commit(sstate, jnp.int32(k), G)
+            workers[k]["theta"] = apply_G(workers[k]["theta"], G)
             workers[k]["strat"] = wst
             losses.append(loss)
             if ms is not None:
@@ -675,13 +675,8 @@ class AsyncTrainer:
             else:
                 down_nnz.append(jnp.count_nonzero(G))
             if eval_fn is not None and eval_every and (e + 1) % eval_every == 0:
-                with rec.span("sim/eval", event=e + 1):
-                    model = ps.global_model(params0, sstate)
-                    evals.append((e + 1, eval_fn(model)))
-                # eval boundary = the sanctioned drain point
-                rec.event("eval", event=e + 1, metric=_jsonable(evals[-1][1]),
-                          **({"metrics": metrics_lib.drain(ms)}
-                             if ms is not None else {}))
+                model = ps.global_model(params0, sstate)
+                evals.append((e + 1, eval_fn(model)))
         final = ps.global_model(params0, sstate)
         per_up = per_down = None
         if up_nnz:
@@ -701,8 +696,6 @@ class AsyncTrainer:
             evals=evals,
             metrics=metrics_lib.drain(ms) if ms is not None else None,
         )
-        _record_run_summary(rec, "serial", hist, up_cost, down_cost,
-                            per_up, per_down)
         return final, sstate, hist
 
     def batched_programs(self, params0, schedule, batch_fn, *,
@@ -777,114 +770,128 @@ class AsyncTrainer:
         place.  Losses, final params, and byte accounting match the serial
         loop exactly on the same schedule (tests/test_async_sim.py).
 
-        ``recorder``/``metrics`` mirror :meth:`run`: host spans per batch,
-        one on-device metrics fold per batch (whole-batch lanes in one
-        dispatch), zero host syncs, no data-plane change.
+        ``recorder`` (a :class:`repro.telemetry.Recorder`) gets host spans
+        — ``batched/init``, per batch ``batched/batch_build``, ``client``
+        (with the upward quantize), ``server``, ``commit`` and ``apply``,
+        each with ``batch=<index>`` and ``size=<b>``, then
+        ``batched/finish`` — which also land on the JAX profiler's clock,
+        the count of programs built during the run, and the run summary.
+        ``metrics=True`` folds each batch into an on-device
+        :class:`~repro.telemetry.metrics.MetricsState` in one dispatch.
+        Neither adds a host sync or changes a data-plane bit.
         """
         from repro.cluster import wire
         from repro import telemetry
         from repro.telemetry import metrics as metrics_lib
 
         rec = recorder if recorder is not None else telemetry.NULL
-        space = ParamSpace.from_tree(params0)
-        sstate = ps.init(params0, self.n_workers)
-        theta0 = space.pack(params0)
-        n = self.n_workers
-        # jnp.copy: donation needs owned buffers, not broadcast views
-        wp = jnp.copy(jnp.broadcast_to(theta0[None], (n, space.total)))
-        ws = jax.tree.map(
-            lambda s: jnp.copy(jnp.broadcast_to(s[None], (n,) + s.shape)),
-            self.strategy.init(params0))
-        client, server, commit, apply_rows, q_up, q_down = \
-            self._batched_stages(space)
-        dense_down = self.secondary_density is None
-        up_mode = self.strategy.quantize
-        down_mode = self.secondary_spec.quantize
-        up_seg = self.strategy.message_seg(space)
-        down_seg = None if dense_down else space.ks(self.secondary_density)
-        up_cost = (wire.frame_bytes_static(up_seg, space.total, up_mode)
-                   if up_seg is not None else None)
-        down_cost = (wire.frame_bytes_static(down_seg, space.total,
-                                             down_mode)
-                     if down_seg is not None else None)
+        with rec.counting_programs():
+            n = self.n_workers
+            with rec.span("batched/init"):
+                space = ParamSpace.from_tree(params0)
+                sstate = ps.init(params0, n)
+                theta0 = space.pack(params0)
+                # jnp.copy: donation needs owned buffers, not broadcast
+                # views
+                wp = jnp.copy(jnp.broadcast_to(theta0[None],
+                                               (n, space.total)))
+                ws = jax.tree.map(
+                    lambda s: jnp.copy(jnp.broadcast_to(s[None],
+                                                        (n,) + s.shape)),
+                    self.strategy.init(params0))
+            client, server, commit, apply_rows, q_up, q_down = \
+                self._batched_stages(space)
+            dense_down = self.secondary_density is None
+            up_mode = self.strategy.quantize
+            down_mode = self.secondary_spec.quantize
+            up_seg = self.strategy.message_seg(space)
+            down_seg = None if dense_down else space.ks(self.secondary_density)
+            up_cost = (wire.frame_bytes_static(up_seg, space.total, up_mode)
+                       if up_seg is not None else None)
+            down_cost = (wire.frame_bytes_static(down_seg, space.total,
+                                                 down_mode)
+                         if down_seg is not None else None)
 
-        batches = batch_schedule(schedule, max_batch=max_batch,
-                                 cut_every=eval_every or None)
-        stal = staleness_of(schedule, self.n_workers)
-        ms = metrics_lib.init(self.n_workers) if metrics else None
-        mstep = self._metrics_step() if metrics else None
-        losses, up_nnz, down_nnz, evals = [], [], [], []
-        e = 0
-        for ids_np in batches:
-            b = len(ids_np)
-            # numpy operands: the jit call converts them on its C++ fast
-            # path — cheaper than one eager device dispatch per array
-            ids = np.asarray(ids_np, np.int32)
-            lrs = np.asarray(
-                [self.lr if lr_fn is None else float(lr_fn(e + i))
-                 for i in range(b)], np.float32)
-            with rec.span("batched/batch_build", size=b):
-                data = [batch_fn(e + i, int(k)) for i, k in enumerate(ids_np)]
-                data = jax.tree.map(lambda *xs: jnp.stack(xs), *data)
-            with rec.span("batched/client", size=b):
-                ws, batch_losses, msgs, nnz_up = client(wp, ws, ids, data,
-                                                        lrs)
-                if q_up is not None:
-                    msgs = q_up(msgs)
-            with rec.span("batched/server", size=b):
-                sstate, G, M_rows = server(sstate, msgs, ids)
-            with rec.span("batched/commit", size=b):
-                if dense_down:
-                    sstate, nnz_dn = commit(sstate, ids, G, M_rows)
-                    down_nnz.append(nnz_dn)
+            batches = batch_schedule(schedule, max_batch=max_batch,
+                                     cut_every=eval_every or None)
+            stal = staleness_of(schedule, self.n_workers)
+            ms = metrics_lib.init(self.n_workers) if metrics else None
+            mstep = self._metrics_step() if metrics else None
+            losses, up_nnz, down_nnz, evals = [], [], [], []
+            e = 0
+            for i, ids_np in enumerate(batches):
+                b = len(ids_np)
+                # numpy operands: the jit call converts them on its C++ fast
+                # path — cheaper than one eager device dispatch per array
+                ids = np.asarray(ids_np, np.int32)
+                lrs = np.asarray(
+                    [self.lr if lr_fn is None else float(lr_fn(e + j))
+                     for j in range(b)], np.float32)
+                with rec.span("batched/batch_build", batch=i, size=b):
+                    data = [batch_fn(e + j, int(k))
+                            for j, k in enumerate(ids_np)]
+                    data = jax.tree.map(lambda *xs: jnp.stack(xs), *data)
+                with rec.span("batched/client", batch=i, size=b):
+                    ws, batch_losses, msgs, nnz_up = client(wp, ws, ids, data,
+                                                            lrs)
+                    if q_up is not None:
+                        msgs = q_up(msgs)
+                with rec.span("batched/server", batch=i, size=b):
+                    sstate, G, M_rows = server(sstate, msgs, ids)
+                with rec.span("batched/commit", batch=i, size=b):
+                    if dense_down:
+                        sstate, nnz_dn = commit(sstate, ids, G, M_rows)
+                        down_nnz.append(nnz_dn)
+                    else:
+                        if q_down is not None:
+                            G = q_down(G)
+                        sstate = commit(sstate, ids, G)
+                with rec.span("batched/apply", batch=i, size=b):
+                    wp = apply_rows(wp, ids, G)
+                losses.append(batch_losses)
+                if ms is not None:
+                    # whole batch folded in one dispatch; staleness is the
+                    # host-precomputed schedule function — still no syncs
+                    ms = mstep(ms, ids, stal[e:e + b].astype(np.int32),
+                               msgs, G)
+                if up_cost is None:
+                    up_nnz.append(nnz_up)
+                e += b
+                if eval_fn is not None and eval_every and e % eval_every == 0:
+                    with rec.span("batched/eval", event=e):
+                        model = ps.global_model(params0, sstate)
+                        evals.append((e, eval_fn(model)))
+                    rec.event("eval", event=e, metric=_jsonable(evals[-1][1]),
+                                  **({"metrics": metrics_lib.drain(ms)}
+                                 if ms is not None else {}))
+            with rec.span("batched/finish"):
+                final = ps.global_model(params0, sstate)
+                n_events = len(schedule)
+                per_up = per_down = None
+                if up_cost is not None:
+                    up_bytes = up_cost * n_events
                 else:
-                    if q_down is not None:
-                        G = q_down(G)
-                    sstate = commit(sstate, ids, G)
-            with rec.span("batched/apply", size=b):
-                wp = apply_rows(wp, ids, G)
-            losses.append(batch_losses)
-            if ms is not None:
-                # whole batch folded in one dispatch; staleness is the
-                # host-precomputed schedule function — still no syncs
-                ms = mstep(ms, ids, stal[e:e + b].astype(np.int32), msgs, G)
-            if up_cost is None:
-                up_nnz.append(nnz_up)
-            e += b
-            if eval_fn is not None and eval_every and e % eval_every == 0:
-                with rec.span("batched/eval", event=e):
-                    model = ps.global_model(params0, sstate)
-                    evals.append((e, eval_fn(model)))
-                rec.event("eval", event=e, metric=_jsonable(evals[-1][1]),
-                          **({"metrics": metrics_lib.drain(ms)}
-                             if ms is not None else {}))
-        final = ps.global_model(params0, sstate)
-        n_events = len(schedule)
-        per_up = per_down = None
-        if up_cost is not None:
-            up_bytes = up_cost * n_events
-        else:
-            per_up = (wire.ENVELOPE_BYTES + wire.dense_frame_bytes(
-                np.asarray(jnp.concatenate(up_nnz)), space.total))
-            up_bytes = int(np.sum(per_up))
-        if down_cost is not None:
-            down_bytes = down_cost * n_events
-        else:
-            per_down = (wire.ENVELOPE_BYTES + wire.dense_frame_bytes(
-                np.asarray(jnp.concatenate(down_nnz)), space.total))
-            down_bytes = int(np.sum(per_down))
-        hist = History(
-            losses=np.asarray(jnp.concatenate(losses), np.float64),
-            worker_ids=np.asarray(schedule),
-            staleness=stal,
-            up_bytes=up_bytes,
-            down_bytes=down_bytes,
-            evals=evals,
-            metrics=metrics_lib.drain(ms) if ms is not None else None,
-        )
-        _record_run_summary(rec, "batched", hist, up_cost, down_cost,
-                            per_up, per_down)
-        return final, sstate, hist
+                    per_up = (wire.ENVELOPE_BYTES + wire.dense_frame_bytes(
+                        np.asarray(jnp.concatenate(up_nnz)), space.total))
+                    up_bytes = int(np.sum(per_up))
+                if down_cost is not None:
+                    down_bytes = down_cost * n_events
+                else:
+                    per_down = (wire.ENVELOPE_BYTES + wire.dense_frame_bytes(
+                        np.asarray(jnp.concatenate(down_nnz)), space.total))
+                    down_bytes = int(np.sum(per_down))
+                hist = History(
+                    losses=np.asarray(jnp.concatenate(losses), np.float64),
+                    worker_ids=np.asarray(schedule),
+                    staleness=stal,
+                    up_bytes=up_bytes,
+                    down_bytes=down_bytes,
+                    evals=evals,
+                    metrics=metrics_lib.drain(ms) if ms is not None else None,
+                )
+            _record_run_summary(rec, "batched", hist, up_cost, down_cost,
+                                per_up, per_down)
+            return final, sstate, hist
 
 
 def run_msgd(

@@ -51,6 +51,8 @@ from typing import Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 
+from repro.telemetry.trace import LAYER
+
 from .sparsify import (
     SparseLeaf,
     quantize_dequantize,
@@ -351,7 +353,8 @@ def quantize_leaf(leaf: SparseLeaf, mode: str) -> SparseLeaf:
     """Wire-quantize one message leaf's values (indices untouched)."""
     if mode == "none":
         return leaf
-    vq, _ = quantize_dequantize(leaf.values, mode)
+    with jax.named_scope(LAYER.quantize):
+        vq, _ = quantize_dequantize(leaf.values, mode)
     return SparseLeaf(values=vq.astype(leaf.values.dtype),
                       indices=leaf.indices, size=leaf.size)
 
@@ -359,7 +362,8 @@ def quantize_leaf(leaf: SparseLeaf, mode: str) -> SparseLeaf:
 def _maybe_quantize_rows(vals, mode: str):
     if mode == "none":
         return vals
-    vq, _ = quantize_dequantize(vals, mode)
+    with jax.named_scope(LAYER.quantize):
+        vq, _ = quantize_dequantize(vals, mode)
     return vq.astype(vals.dtype)
 
 
@@ -368,7 +372,9 @@ def select(x, k: int, spec: CompressionSpec = DEFAULT_SPEC) -> SparseLeaf:
     quantization)."""
     flat = x.reshape(-1)
     eng = resolve_engine(spec, int(flat.shape[0]))
-    return quantize_leaf(eng.select(flat, k), spec.quantize)
+    with jax.named_scope(LAYER.select):
+        leaf = eng.select(flat, k)
+    return quantize_leaf(leaf, spec.quantize)
 
 
 def select_rows(x2d, k: int, spec: CompressionSpec = DEFAULT_SPEC):
@@ -376,7 +382,8 @@ def select_rows(x2d, k: int, spec: CompressionSpec = DEFAULT_SPEC):
 
     Returns (vals (S, k), idx (S, k) int32 local per-row)."""
     eng = resolve_engine(spec, int(x2d.shape[1]))
-    vals, idx = eng.select_rows(x2d, k)
+    with jax.named_scope(LAYER.select):
+        vals, idx = eng.select_rows(x2d, k)
     return _maybe_quantize_rows(vals, spec.quantize), idx
 
 
@@ -396,7 +403,8 @@ def samomentum_step(u, g, *, momentum: float, lr: float, k: int,
     else:
         uacc = velocity_accumulate(u, g, momentum=momentum, lr=lr)
         flat = uacc.reshape(-1)
-        msg = eng.select(flat, k)
+        with jax.named_scope(LAYER.select):
+            msg = eng.select(flat, k)
         mask = support_mask(msg.indices, flat.shape[0])
         u_new = samomentum_rescale(flat, mask, momentum).reshape(u.shape)
     return quantize_leaf(msg, spec.quantize), u_new
@@ -418,7 +426,8 @@ def _samomentum_step_blockwise(u, g, eng: BlockwiseEngine, *, momentum, lr,
     from repro.kernels import ops
 
     uacc = velocity_accumulate(u, g, momentum=momentum, lr=lr)
-    msg = eng.select(uacc.reshape(-1), k)
+    with jax.named_scope(LAYER.select):
+        msg = eng.select(uacc.reshape(-1), k)
     thr = jnp.min(jnp.abs(msg.values))
     # uacc is already materialized for the selection above, so feed it back
     # through the fused kernel as both operands with (m, 1 - m):
@@ -445,8 +454,9 @@ def quantize_arena(msg: SparseLeaf, mode: str, seg) -> SparseLeaf:
     """
     if mode == "none":
         return msg
-    return SparseLeaf(values=quantize_segments(msg.values, mode, seg),
-                      indices=msg.indices, size=msg.size)
+    with jax.named_scope(LAYER.quantize):
+        values = quantize_segments(msg.values, mode, seg)
+    return SparseLeaf(values=values, indices=msg.indices, size=msg.size)
 
 
 def samomentum_step_arena(u, g, space, *, momentum: float, lr: float,

@@ -51,7 +51,6 @@ def run_async_scan(
     lr: float,
     secondary_density: float | None = None,
     secondary_spec: CompressionSpec = engine_lib.EXACT_SPEC,
-    recorder=None,
     metrics: bool = False,
 ):
     """Run the whole schedule in one jitted scan.
@@ -66,13 +65,10 @@ def run_async_scan(
     the optimization-barrier-staged stage outputs, so the data-plane op
     sequence — and therefore every loss/param/byte bit — is unchanged.
     With it off, the compiled program is literally the pre-telemetry one.
-    ``recorder`` traces the two host phases (build+compile, execute).
     """
     from repro.cluster import wire  # codec quantizer + byte accounting
-    from repro import telemetry
     from repro.telemetry import metrics as metrics_lib
 
-    rec = recorder if recorder is not None else telemetry.NULL
     space = ParamSpace.from_tree(params0)
     up_mode = strategy.quantize
     down_mode = secondary_spec.quantize
@@ -165,11 +161,9 @@ def run_async_scan(
                  jnp.asarray(stal, jnp.int32), batches))
             return sstate, out, ms
 
-        with rec.span("scan/build_and_compile"):
-            ms0 = metrics_lib.init(n_workers)
-        with rec.span("scan/execute"):
-            sstate, (losses, up_nnz, down_nnz), ms = run(
-                sstate0, wp0, ws0, schedule, batches, ms0, stal_np)
+        sstate, (losses, up_nnz, down_nnz), ms = run(
+            sstate0, wp0, ws0, schedule, batches,
+            metrics_lib.init(n_workers), stal_np)
     else:
         @partial(jax.jit, donate_argnums=(0,))
         def run(sstate0, wp0, ws0, schedule, batches):
@@ -179,9 +173,8 @@ def run_async_scan(
             return sstate, out
 
         ms = None
-        with rec.span("scan/execute"):
-            sstate, (losses, up_nnz, down_nnz) = run(
-                sstate0, wp0, ws0, schedule, batches)
+        sstate, (losses, up_nnz, down_nnz) = run(
+            sstate0, wp0, ws0, schedule, batches)
 
     n_events = len(schedule)
     env = wire.ENVELOPE_BYTES
@@ -202,17 +195,4 @@ def run_async_scan(
         evals=[],
         metrics=metrics_lib.drain(ms) if ms is not None else None,
     )
-    if rec.enabled:
-        def per_event(seg, mode, nnz):
-            if seg is not None:
-                return np.full(n_events,
-                               wire.frame_bytes_static(seg, space.total,
-                                                       mode))
-            return env + wire.dense_frame_bytes(
-                np.asarray(nnz, dtype=np.int64), space.total)
-
-        async_sim._record_run_summary(
-            rec, "scan", hist, None, None,
-            per_event(up_seg, up_mode, up_nnz),
-            per_event(down_seg, down_mode, down_nnz))
     return ps.global_model(params0, sstate), hist

@@ -33,6 +33,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.telemetry.trace import LAYER
+
 from . import engine as engine_lib
 from .engine import CompressionSpec
 from .paramspace import ParamSpace, ShardSpec
@@ -66,11 +68,12 @@ def init(params, n_workers: int) -> ServerState:
 
 def receive(state: ServerState, msg) -> ServerState:
     """Apply one worker's (sparse or dense) arena update message to M."""
-    if isinstance(msg, SparseLeaf):
-        new_M = _scatter_add(state.M, msg.indices, -msg.values)
-    else:  # dense flat arena (ASGD)
-        new_M = state.M - msg
-    return state._replace(M=new_M, t=state.t + 1)
+    with jax.named_scope(LAYER.receive):
+        if isinstance(msg, SparseLeaf):
+            new_M = _scatter_add(state.M, msg.indices, -msg.values)
+        else:  # dense flat arena (ASGD)
+            new_M = state.M - msg
+        return state._replace(M=new_M, t=state.t + 1)
 
 
 def send_select(
@@ -87,7 +90,8 @@ def send_select(
     :func:`send_commit` is then fed exactly what the client decoded, so
     server bookkeeping always tracks the shipped bits.
     """
-    diff = state.M - state.v[worker_id]
+    with jax.named_scope(LAYER.receive):
+        diff = state.M - state.v[worker_id]
     if secondary_density is None:
         return diff
     spec_raw = dataclasses.replace(spec, quantize="none")
@@ -102,10 +106,12 @@ def send_commit(state: ServerState, worker_id, G) -> ServerState:
     quantization.  A dense G means "everything": v_k snaps to M exactly
     (``v + (M - v)`` would lose bits to f32 cancellation).
     """
-    if isinstance(G, SparseLeaf):
-        new_v = _scatter_add_row(state.v, worker_id, G.indices, G.values)
-    else:
-        new_v = state.v.at[worker_id].set(state.M)
+    with jax.named_scope(LAYER.commit):
+        if isinstance(G, SparseLeaf):
+            new_v = _scatter_add_row(state.v, worker_id, G.indices,
+                                     G.values)
+        else:
+            new_v = state.v.at[worker_id].set(state.M)
     return state._replace(v=new_v)
 
 
@@ -129,13 +135,15 @@ def send_commit_rows(state: ServerState, worker_ids, G,
     """
     if isinstance(G, SparseLeaf):
         from repro.kernels import ops
-        new_v = ops.scatter_add_rows(state.v, worker_ids, G.indices,
-                                     G.values)
+        with jax.named_scope(LAYER.commit):
+            new_v = ops.scatter_add_rows(state.v, worker_ids, G.indices,
+                                         G.values)
     else:
         if M_rows is None:
             raise ValueError("dense batched commit needs the per-event "
                              "prefix M_rows (see batched_server_step_fn)")
-        new_v = state.v.at[worker_ids].set(M_rows)
+        with jax.named_scope(LAYER.commit):
+            new_v = state.v.at[worker_ids].set(M_rows)
     return state._replace(v=new_v)
 
 
@@ -183,9 +191,10 @@ def reset_worker(state: ServerState, worker_id: int) -> ServerState:
 
 def apply_update(theta: jax.Array, G) -> jax.Array:
     """Worker-side arena update  theta <- theta + G  (Eq. 5) — ONE scatter."""
-    if isinstance(G, SparseLeaf):
-        return _scatter_add(theta, G.indices, G.values)
-    return theta + G.astype(theta.dtype)
+    with jax.named_scope(LAYER.apply):
+        if isinstance(G, SparseLeaf):
+            return _scatter_add(theta, G.indices, G.values)
+        return theta + G.astype(theta.dtype)
 
 
 def apply_to_params(params, G):

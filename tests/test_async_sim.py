@@ -193,22 +193,31 @@ def test_batched_max_batch_one_matches_serial():
 # ---------------------------------------------------------------------------
 
 _METRICS_CONFIGS = [
-    # (strategy, kwargs, secondary_density)
-    ("dgs", dict(density=0.1, quantize="int8"), 0.1),
-    ("dgc_async", dict(density=0.1), 0.1),
-    ("asgd", dict(), None),
+    # (strategy, kwargs, secondary_density, the telemetry switched on);
+    # the metrics cases keep the ids they had before the recorder cases
+    pytest.param("dgs", dict(density=0.1, quantize="int8"), 0.1, "metrics",
+                 id="dgs-kw0-0.1"),
+    pytest.param("dgc_async", dict(density=0.1), 0.1, "metrics",
+                 id="dgc_async-kw1-0.1"),
+    pytest.param("asgd", dict(), None, "metrics", id="asgd-kw2-None"),
+    pytest.param("dgs", dict(density=0.1, quantize="int8", engine="sampled"),
+                 0.1, "recorder", id="dgs-sampled-recorder"),
+    pytest.param("asgd", dict(), None, "recorder", id="asgd-recorder"),
 ]
 
 
-@pytest.mark.parametrize("name,kw,sec", _METRICS_CONFIGS)
-def test_metrics_do_not_change_bits(name, kw, sec):
+@pytest.mark.parametrize("name,kw,sec,on", _METRICS_CONFIGS)
+def test_metrics_do_not_change_bits(name, kw, sec, on):
     """DESIGN.md §11's contract: metrics ON is bit-identical to metrics
     OFF — losses, final params, byte totals — in every runner (serial,
     batched, scan), and all three runners agree on the drained
-    MetricsState itself."""
+    MetricsState itself.  A Recorder on the batched loop changes no bit
+    either; it gets the loop's spans, and a second run of a schedule
+    builds no program."""
     import jax
     import jax.numpy as jnp
 
+    from repro import telemetry
     from repro.core import make_strategy
     from repro.core.scan_runner import run_async_scan
     from repro.telemetry import metrics as metrics_lib
@@ -218,6 +227,29 @@ def test_metrics_do_not_change_bits(name, kw, sec):
     sched = async_sim.make_schedule(n_workers, n_events, seed=3, hetero=0.8)
     tr = async_sim.AsyncTrainer(make_strategy(name, **kw), grad_fn,
                                 n_workers, lr=0.05, secondary_density=sec)
+
+    if on == "recorder":
+        rec = telemetry.Recorder()
+        f_off, _, h_off = tr.run_batched(params0, sched, batch_fn,
+                                         max_batch=4)
+        f_on, _, h_on = tr.run_batched(params0, sched, batch_fn,
+                                       max_batch=4, recorder=rec)
+        np.testing.assert_array_equal(h_off.losses, h_on.losses)
+        assert (h_off.up_bytes, h_off.down_bytes) == (h_on.up_bytes,
+                                                      h_on.down_bytes)
+        for a, b in zip(jax.tree.leaves(f_off), jax.tree.leaves(f_on)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        n_batches = len(async_sim.batch_schedule(sched, max_batch=4))
+        spans = [ev for ev in rec._trace if ev["ph"] == "X"]
+        names = [ev["name"] for ev in spans]
+        assert names[0] == "batched/init" and names[-1] == "batched/finish"
+        for stage in ("batch_build", "client", "server", "commit", "apply"):
+            per = [ev["args"] for ev in spans
+                   if ev["name"] == f"batched/{stage}"]
+            assert [a["batch"] for a in per] == list(range(n_batches))
+            assert sum(a["size"] for a in per) == n_events
+        assert not [k for k in rec.counters if k.startswith("programs/")]
+        return
 
     f_off, _, h_off = tr.run(params0, sched, batch_fn)
     f_on, _, h_on = tr.run(params0, sched, batch_fn, metrics=True)
@@ -267,6 +299,50 @@ def test_metrics_do_not_change_bits(name, kw, sec):
         a.pop("update_mag_hist"), b.pop("update_mag_hist")
         assert a == b
         assert sum(other["update_mag_hist"]["counts"]) == n_events
+
+
+@pytest.mark.parametrize("name,kw,eng", [
+    ("dgs", dict(density=0.1, quantize="int8"), "exact"),
+    ("dgs", dict(density=0.1, quantize="int8"), "sampled"),
+    ("asgd", dict(), "exact"),
+])
+def test_batched_stage_programs_carry_layer_scopes(name, kw, eng):
+    """Each batched stage program's compiled HLO names its layers in the
+    ops' ``op_name`` metadata, which a device trace keeps: selection
+    under the client strategy and the server's secondary select, wire
+    quantization in the quantizers."""
+    import re
+
+    from repro.core import make_strategy
+    from repro.core.engine import CompressionSpec
+    from repro.telemetry.trace import LAYER
+
+    params0, grad_fn, batch_fn = _parity_problem()
+    sched = async_sim.make_schedule(5, 40, seed=3, hetero=0.9)
+    sparse = name != "asgd"
+    spec = CompressionSpec(engine=eng, quantize="int8")
+    tr = async_sim.AsyncTrainer(
+        make_strategy(name, engine=eng, **kw) if sparse
+        else make_strategy(name), grad_fn, 5, lr=0.05,
+        secondary_density=0.1 if sparse else None, secondary_spec=spec)
+    want = {
+        "run": {LAYER.grad, LAYER.strategy} | ({LAYER.select} if sparse
+                                                else set()),
+        "<lambda>": {LAYER.quantize},
+        "server_batch": {LAYER.receive} | ({LAYER.select} if sparse
+                                           else set()),
+        "commit": {LAYER.commit},
+        "apply_rows": {LAYER.apply},
+    }
+    seen = set()
+    for fn, args in tr.batched_programs(params0, sched, batch_fn,
+                                        max_batch=4):
+        text = fn.lower(*args).compile().as_text()
+        paths = " ".join(re.findall(r'op_name="([^"]*)"', text))
+        got = set(re.findall(r"layer\.\w+", paths))
+        assert got == want[fn.__name__], (fn.__name__, got)
+        seen.add(fn.__name__)
+    assert seen == set(want) - (set() if sparse else {"<lambda>"})
 
 
 @pytest.mark.parametrize("name,kw,sec", [
