@@ -15,12 +15,13 @@ down in ``kernels/ref.py``:
 * ``exact``     — ``lax.top_k`` over |x|.  The oracle: every other engine
                   is tested against it.  Right answer below ~1M elements.
 * ``sampled``   — DGC-style sampled-threshold estimation
-                  (``sparsify.sampled_threshold`` + a sort-free cumsum
-                  compaction): estimate the k-th magnitude from a strided
-                  subsample, stream-compact the passers into <= 4k
-                  candidate slots, exact top-k over only those candidates.
-                  No full-width sort ever runs; exact while <= 4k
-                  coordinates pass the estimate.
+                  (``sparsify.sampled_threshold`` + a sort- and
+                  scatter-free compaction): estimate the k-th magnitude
+                  from a strided subsample, take the passers' running
+                  count, search it for the first <= 4k passers in index
+                  order (the candidate slots), exact top-k over only
+                  those candidates.  No full-width sort ever runs;
+                  exact while <= 4k coordinates pass the estimate.
 * ``blockwise`` — the Pallas hot path: ``kernels.ops.hierarchical_topk``
                   (per-VMEM-block top-r candidates, no sort, one HBM pass)
                   for selection, ``samomentum_fused`` for the fused
@@ -169,13 +170,46 @@ class ExactEngine:
         return jnp.take_along_axis(x2d, idx, axis=1), idx
 
 
+def _first_reaching(csum, cap: int):
+    """Column where each row's running count ``csum`` (S, n) first reaches
+    1, 2, ..., cap; -1 past the row's total.
+
+    A three-level search, no scatter and no per-element gather: the count
+    is cut into rows of 128 values, grouped 128 rows to a block.  Per rank:
+    the block is the number of block ends below it (a compare over the
+    n / 16384 block ends), the row the number of that block's row ends
+    below it, the column the number of that row's values below it (one
+    gathered 128-value row each).  A binary search over the count
+    (``jnp.searchsorted``) gathers ~log2(n) single values per rank instead:
+    3.9 ms against 1.1 ms for one 4.7M-value row on a TPU v5e.
+    """
+    S, n = csum.shape
+    L = 128                                       # one lane row of a TPU
+    pad = -n % (L * L)
+    c3 = jnp.pad(csum, ((0, 0), (0, pad)), mode="edge").reshape(S, -1, L)
+    c2 = c3[:, :, L - 1].reshape(S, -1, L)        # count at each row's end
+    ranks = jnp.arange(1, cap + 1, dtype=jnp.int32)
+    r = ranks[:, None]
+    rows = jax.vmap(lambda c, i: c[i])            # (S, m, L)[(S, cap)]
+    # a rank past the row's total overruns the last block; its slot is
+    # masked below, whatever the clamped gathers read
+    blk = jnp.sum(c2[:, None, :, L - 1] < r, axis=2, dtype=jnp.int32)
+    row = blk * L + jnp.sum(rows(c2, blk) < r, axis=2, dtype=jnp.int32)
+    col = row * L + jnp.sum(rows(c3, row) < r, axis=2, dtype=jnp.int32)
+    return jnp.where(ranks <= csum[:, -1:], col, -1)
+
+
 def _threshold_compact_rows(x2d, thr, k: int, *, cap_factor: int = 4):
     """Exactly-k selection of threshold passers without a full-width sort.
 
     This is the point of the sampled threshold: the O(n) work is one
-    streaming pass (cumsum rank + scatter) that compacts the passers into
-    at most ``cap = cap_factor * k`` candidate slots in index order; an
-    exact ``top_k`` then runs over only those candidates (k << n sort).
+    streaming pass (the passers' running count) that compacts the passers
+    into at most ``cap = cap_factor * k`` candidate slots in index order;
+    an exact ``top_k`` then runs over only those candidates (k << n sort).
+    Slot j holds the column where the running count first reaches j + 1,
+    found by searching the count for each slot rank (``_first_reaching``);
+    a per-element scatter into the slots costs n colliding updates and
+    runs far below the bandwidth.
     The selection is exact whenever at most ``cap`` coordinates pass the
     threshold — the estimator targets ~k passers, so the factor-4 cap
     absorbs estimation error; beyond that, surplus passers are dropped in
@@ -188,17 +222,12 @@ def _threshold_compact_rows(x2d, thr, k: int, *, cap_factor: int = 4):
 
     x2d: (S, n); thr: (S, 1).  Returns (vals (S, k), idx (S, k) int32).
     """
-    S, n = x2d.shape
+    n = x2d.shape[1]
     mag = jnp.abs(x2d)
     cap = int(min(n, cap_factor * k))
     mask = (mag >= thr) & (mag > 0.0)
-    rank = jnp.cumsum(mask, axis=1) - 1                   # rank among passers
-    ok = mask & (rank < cap)
-    rows = jnp.arange(S, dtype=jnp.int32)[:, None]
-    cols = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (S, n))
-    slot = jnp.where(ok, rank, cap)                       # cap = spill column
-    cidx = jnp.full((S, cap + 1), -1, jnp.int32).at[rows, slot].set(
-        jnp.where(ok, cols, -1))[:, :cap]
+    csum = jnp.cumsum(mask, axis=1, dtype=jnp.int32)     # passers in [0, i]
+    cidx = _first_reaching(csum, cap)                     # -1: empty slot
     valid_c = cidx >= 0
     cvals = jnp.where(
         valid_c,
@@ -221,10 +250,12 @@ class SampledEngine:
 
     The k-th |x| is estimated from a ``sample_size`` strided subsample
     (``sparsify.sampled_threshold``), then the passers are compacted to a
-    small candidate set and top-k'd WITHOUT a full-tensor sort
-    (``_threshold_compact_rows``) — exact while at most ``4k`` coordinates
-    pass the estimate, index-order truncated beyond that; shapes stay
-    static and the per-element work is one streaming pass.
+    small candidate set and top-k'd WITHOUT a full-tensor sort or a
+    per-element scatter (``_threshold_compact_rows``: a running count of
+    the passers, searched for each candidate slot) — exact while at most
+    ``4k`` coordinates pass the estimate, index-order truncated beyond
+    that; shapes stay static and the per-element work is one streaming
+    pass.
     """
 
     name = "sampled"

@@ -16,10 +16,12 @@ should go through the engine layer rather than these directly:
   ``sampled`` engine's estimator) for very large tensors, where an exact
   top-k of a 100M-element gradient would dominate step time.  The live
   selection against the estimate is ``engine._threshold_compact_rows``
-  (sort-free compaction + candidate top-k); ``threshold_select`` here is
-  the magnitude-keyed *reference* selector for threshold-based selection
-  (full-width keyed top_k, support provably identical to exact top-k) kept
-  as the semantics oracle it is tested against.
+  (sort- and scatter-free compaction: a search of the passers' running
+  count for each candidate slot, then a candidate top-k);
+  ``threshold_select`` here is the magnitude-keyed *reference* selector
+  for threshold-based selection (full-width keyed top_k, support provably
+  identical to exact top-k) kept as the semantics oracle it is tested
+  against.
 """
 from __future__ import annotations
 

@@ -187,6 +187,85 @@ class TestSampledNoStarvation:
             set(np.asarray(exact.indices).tolist())
 
 
+def _np_threshold_compact(x2d, thr, k, cap_factor=4):
+    """NumPy oracle of ``engine._threshold_compact_rows``'s documented
+    semantics: the first ``cap`` passers in index order (exact zeros never
+    pass), a stable exact top-k of them by magnitude, and padding that
+    duplicates the strongest candidate with value 0."""
+    S, n = x2d.shape
+    cap = min(n, cap_factor * k)
+    vals = np.zeros((S, k), x2d.dtype)
+    idx = np.zeros((S, k), np.int32)
+    for s in range(S):
+        mag = np.abs(x2d[s])
+        cand = np.flatnonzero((mag >= thr[s, 0]) & (mag > 0.0))[:cap]
+        chosen = cand[np.argsort(-mag[cand], kind="stable")[:k]]
+        m = chosen.size
+        idx[s, :m] = chosen
+        idx[s, m:] = chosen[0] if m else 0
+        vals[s, :m] = x2d[s, chosen]
+    return vals, idx
+
+
+def _thr_for_passers(x2d, count):
+    """Per-row threshold that ``count`` magnitudes (plus ties) reach."""
+    mag = -np.sort(-np.abs(x2d), axis=1)
+    return mag[:, count - 1:count]
+
+
+class TestThresholdCompaction:
+    @pytest.mark.parametrize("n", [1000, 4096, 40000])
+    @pytest.mark.parametrize("S", [1, 3])
+    @pytest.mark.parametrize("case", [
+        "fewer_than_k", "k_to_cap", "beyond_cap", "zero_thr"])
+    def test_matches_numpy_oracle_bitforbit(self, case, S, n):
+        k = 13
+        cap = 4 * k
+        rng = np.random.default_rng(n * 10 + S)
+        x = rng.normal(size=(S, n)).astype(np.float32)
+        if case == "zero_thr":
+            # quarter steps (ties in magnitude), ~3 cap nonzeros, the rest
+            # exact zeros
+            x = np.round(x * 2.0) / 4.0
+            x[rng.random((S, n)) > 3 * cap / n] = 0.0
+        # strong passers at the ends and starts of the search's 128-value
+        # rows and 16384-value blocks
+        edges = [p for p in (127, 128, 255, 16383, 16384) if p < n]
+        x[:, edges] = 5.0 + np.arange(len(edges), dtype=np.float32)
+        if case == "zero_thr":
+            thr = np.zeros((S, 1), np.float32)
+        else:
+            count = {"fewer_than_k": k // 2, "k_to_cap": 3 * k,
+                     "beyond_cap": 6 * k}[case]
+            thr = _thr_for_passers(x, count)
+        passers = ((np.abs(x) >= thr) & (x != 0.0)).sum(axis=1)
+        lo, hi = {"fewer_than_k": (1, k - 1), "k_to_cap": (k, cap),
+                  "beyond_cap": (cap + 1, n), "zero_thr": (cap + 1, n)}[case]
+        assert np.all((passers >= lo) & (passers <= hi)), passers
+        if case == "zero_thr":
+            assert np.all((x == 0.0).sum(axis=1) > 0)
+        vals, idx = E._threshold_compact_rows(jnp.asarray(x),
+                                              jnp.asarray(thr), k)
+        ov, oi = _np_threshold_compact(x, thr, k)
+        np.testing.assert_array_equal(np.asarray(idx), oi)
+        np.testing.assert_array_equal(np.asarray(vals), ov)
+        assert np.asarray(idx).dtype == np.int32
+
+    @pytest.mark.parametrize("rows", [False, True])
+    def test_sampled_selection_lowers_without_scatter(self, rows):
+        """The candidate compaction gathers and searches; a per-element
+        scatter into the candidate slots (n colliding updates) must not
+        come back."""
+        n, k = 1 << 16, 64           # n >> cap = 4k
+        eng = E.SampledEngine()
+        if rows:
+            fn, x = eng.select_rows, jnp.zeros((3, n))
+        else:
+            fn, x = eng.select, jnp.zeros((n,))
+        text = jax.jit(fn, static_argnums=1).lower(x, k).as_text()
+        assert "scatter" not in text
+
+
 class TestAutoDispatch:
     def test_auto_respects_sampled_threshold_above(self):
         spec = CompressionSpec(engine="auto", sampled_threshold_above=1000)
