@@ -512,13 +512,22 @@ def make_mesh_batched_commit(dense_down: bool):
 
 
 def make_batched_apply():
-    """jit(vmap(worker apply)) over the batch rows of the stacked worker
-    models; donates ``wp`` (the (n_workers, total) buffer updates in
-    place)."""
+    """jit(worker apply) over the batch rows of the stacked worker models;
+    donates ``wp`` (the (n_workers, total) buffer updates in place).
+
+    A sparse batch is scattered straight into ``wp``, one flat scatter
+    per row (``kernels.ops.scatter_add_rows``): the batch's ``ids`` are
+    pairwise distinct (``batch_schedule``), so it is bit-equal to the
+    serial :func:`ps.apply_update` of each row.  A dense batch (ASGD) is the
+    vmapped ``apply_update`` of the gathered rows, written back.
+    """
     vapply = jax.vmap(ps.apply_update)
 
     def apply_rows(wp, ids, G):
         with jax.named_scope(LAYER.apply):
+            if isinstance(G, SparseLeaf):
+                from repro.kernels import ops
+                return ops.scatter_add_rows(wp, ids, G.indices, G.values)
             return wp.at[ids].set(vapply(wp[ids], G))
 
     return jax.jit(apply_rows, donate_argnums=(0,))

@@ -19,8 +19,9 @@ State lives in the FLAT PARAMETER ARENA (core/paramspace.py, DESIGN.md §8):
 ``(n_workers, total)`` buffer; messages are a single global-index
 :class:`~repro.core.sparsify.SparseLeaf` over the arena (or one dense
 ``(total,)`` vector).  Receive, commit, and worker apply are therefore ONE
-fused scatter-add each (``kernels.ops.scatter_add`` — the Pallas blocked
-kernel on TPU) instead of one small scatter per tensor per event.
+fused scatter-add each (``kernels.ops.scatter_add*`` — one XLA scatter
+per arena row, in place in the buffer the stage program donates) instead
+of one small scatter per tensor per event.
 Secondary *selection* stays paper-faithful per-tensor top-k: the arena is
 offset-sliced back into leaf views, each selected through the engine
 registry, and the indices rebased by leaf offset (``ParamSpace.select``).
@@ -122,9 +123,10 @@ def send_commit_rows(state: ServerState, worker_ids, G,
     The batched event loop's commit stage (Eq. 4, one event per batch
     lane).  ``worker_ids`` must be pairwise distinct — the scheduler's
     batching rule (``async_sim.batch_schedule``) guarantees it — so the
-    rows are disjoint and ONE fused multi-row scatter
-    (``kernels.ops.scatter_add_rows``) is bit-equal to committing the
-    events one :func:`send_commit` at a time in any order.
+    rows are disjoint and the multi-row scatter
+    (``kernels.ops.scatter_add_rows``, one flat scatter per row, in place
+    in the donated ``v``) is bit-equal to committing the events one
+    :func:`send_commit` at a time in any order.
 
     ``G`` is the stacked shipped batch: one SparseLeaf with ``(B, k)``
     values/indices, or a dense ``(B, total)`` stack.  Dense commits snap

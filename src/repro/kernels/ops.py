@@ -1,5 +1,12 @@
 """Jit'd public wrappers around the Pallas kernels (padding, reshaping,
 candidate combine).  These are what the rest of the framework calls.
+
+The arena's sparse scatter-adds (:func:`scatter_add`,
+:func:`scatter_add_row`, :func:`scatter_add_rows`) are XLA scatters on
+every backend, one per arena row, in place in the donated buffer.  The
+blocked Pallas scatter (:func:`scatter_apply`, :func:`scatter_apply_rows`)
+streams every block of its rows through VMEM; the blockwise engine's
+support repair uses it.
 """
 from __future__ import annotations
 
@@ -82,51 +89,46 @@ def hierarchical_topk(x, *, k: int, r: int | None = None,
     return cvals[sel], cidx[sel]
 
 
-def scatter_add(dense, indices, values, *, interpret: bool | None = None):
+def scatter_add(dense, indices, values):
     """One fused scatter-add on a flat arena: ``dense.at[indices].add(v)``.
 
-    The single entry point behind the arena runtime's three hot scatters
-    (server receive, ``v_k`` commit, worker apply).  On TPU it routes to the
-    blocked Pallas :func:`scatter_apply` kernel (one HBM pass over the
-    parameter vector, bucketed contiguous DMA for the updates); elsewhere it
-    stays on the XLA scatter — interpret-mode Pallas would serialize the
-    block loop in Python and lose the very dispatch-count war the arena
-    wins.  Duplicate indices accumulate in both paths.
+    The entry point behind the arena runtime's flat scatters (server
+    receive, worker apply).  One XLA scatter on every backend: inside a
+    stage program that donates ``dense`` it updates the buffer in place,
+    touching only the indexed values.  Duplicate indices accumulate.
     """
-    if resolve_interpret(interpret):
-        return dense.at[indices].add(values.astype(dense.dtype))
-    return scatter_apply(dense, indices, values, interpret=False)
+    return dense.at[indices].add(values.astype(dense.dtype))
 
 
-def scatter_add_row(dense2d, row, indices, values, *,
-                    interpret: bool | None = None):
+def scatter_add_row(dense2d, row, indices, values):
     """``dense2d.at[row, indices].add(values)`` — one worker row of the
-    server's ``v`` buffer.  Off-TPU this is a single 2-D XLA scatter (no
-    row gather/set round trip); on TPU the row is sliced, run through the
-    blocked Pallas kernel, and written back."""
-    if resolve_interpret(interpret):
-        return dense2d.at[row, indices].add(values.astype(dense2d.dtype))
-    new_row = scatter_apply(dense2d[row], indices, values, interpret=False)
-    return dense2d.at[row].set(new_row)
+    server's ``v`` buffer.
+
+    The row is sliced out, scattered into as a flat arena
+    (:func:`scatter_add`) and written back in place.  A 2-D scatter
+    straight into ``dense2d`` would be simpler, but the TPU compiler
+    lowers it by copying the whole ``(n_workers, total)`` buffer to a
+    flat layout and back; this form moves one row each way.
+    """
+    flat = jax.lax.dynamic_index_in_dim(dense2d, row, 0, keepdims=False)
+    return jax.lax.dynamic_update_index_in_dim(
+        dense2d, scatter_add(flat, indices, values), row, 0)
 
 
-def scatter_add_rows(dense2d, rows, idx2d, vals2d, *,
-                     interpret: bool | None = None):
-    """Batched multi-row scatter-add — the batched commit stage's ONE op.
+def scatter_add_rows(dense2d, rows, idx2d, vals2d):
+    """Batched multi-row scatter-add — the batched commit and apply
+    stages' scatter.
 
     ``dense2d.at[rows[b], idx2d[b]].add(vals2d[b])`` for every batch lane
-    ``b``.  ``rows`` must be pairwise distinct (the batching rule —
-    ``async_sim.batch_schedule``); the per-lane scatters then touch
-    disjoint rows, so one fused scatter is bit-equal to any serial order
-    of :func:`scatter_add_row` calls.  Off-TPU this is a single 2-D XLA
-    scatter; on TPU the rows are gathered, run through the blocked
-    Pallas kernel (one grid over every lane's blocks), and written back.
+    ``b``: one :func:`scatter_add_row` per lane, in lane order, in place
+    where the stage program donates ``dense2d``.  The batching rule
+    (``async_sim.batch_schedule``) makes ``rows`` pairwise distinct, so
+    the lanes touch disjoint rows and any serial order of
+    :func:`scatter_add_row` calls gives the same bits.
     """
-    if resolve_interpret(interpret):
-        return dense2d.at[rows[:, None], idx2d].add(
-            vals2d.astype(dense2d.dtype))
-    sub = scatter_apply_rows(dense2d[rows], idx2d, vals2d, interpret=False)
-    return dense2d.at[rows].set(sub)
+    for b in range(rows.shape[0]):
+        dense2d = scatter_add_row(dense2d, rows[b], idx2d[b], vals2d[b])
+    return dense2d
 
 
 def _bucket_blocked(nb: int, cap: int, indices, values):

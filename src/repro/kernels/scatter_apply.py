@@ -20,11 +20,13 @@ than ``cap`` updates spills the surplus to an XLA scatter in the wrapper,
 so the op stays exact while the bucket operands stay
 ``O(n_blocks * cap)`` however large ``k`` grows.
 
-This is the TPU fast path behind ``ops.scatter_add`` — the ONE fused
-scatter the flat-arena runtime (core/paramspace.py) runs per event for
-server receive, ``v_k`` commit, and worker apply.  The multi-row commit
-runs through the same kernel: every lane's blocks are stacked into one
-``(n_rows * n_blocks, ROWS, LANE)`` grid.
+Every block of every row streams through the kernel, touched or not, so
+it pays off only where updates are dense in the blocks: the blockwise
+engine's support repair (``ops.scatter_apply``).  The flat-arena
+runtime's receive, commit and apply, a few updates per mille of a row,
+take the in-place XLA scatter of ``ops.scatter_add*`` instead.  The
+multi-row form (``ops.scatter_apply_rows``) stacks every lane's blocks
+into one ``(n_rows * n_blocks, ROWS, LANE)`` grid.
 
 Semantics contract: kernels/ref.py::scatter_accumulate_ref.
 """

@@ -112,7 +112,7 @@ def quantize_pack(values, *, mode: str, seg: tuple,
         decoder on the far side reconstructs (== ``quantize_segments``).
 
     ``pallas=None`` routes the tern packer by backend (the Pallas kernel on
-    TPU, plain XLA elsewhere — same convention as ``ops.scatter_add``);
+    TPU, plain XLA elsewhere);
     tests force the kernel with ``pallas=True, interpret=True``.  bf16 and
     int8 take the XLA ops everywhere.
     """

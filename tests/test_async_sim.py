@@ -401,3 +401,70 @@ def test_batched_programs_prebuild_every_stage(name, kw, sec):
     assert (h1.up_bytes, h1.down_bytes) == (h2.up_bytes, h2.down_bytes)
     for a, b in zip(jax.tree.leaves(f1), jax.tree.leaves(f2)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _apply_batch(sparse):
+    """A stacked worker buffer, distinct batch rows and a shipped batch:
+    a SparseLeaf of distinct per-row indices, or a dense (B, total)."""
+    import jax.numpy as jnp
+
+    from repro.core.sparsify import SparseLeaf
+
+    rng = np.random.default_rng(5)
+    w, n, k = 6, 2000, 30
+    wp = rng.normal(size=(w, n)).astype(np.float32)
+    wp[:, ::5] = -0.0
+    ids = np.asarray([4, 1, 2], np.int32)
+    if sparse:
+        idx = np.stack([rng.choice(n, k, replace=False)
+                        for _ in ids]).astype(np.int32)
+        G = SparseLeaf(values=jnp.asarray(
+            rng.normal(size=(len(ids), k)).astype(np.float32)),
+            indices=jnp.asarray(idx), size=n)
+    else:
+        G = jnp.asarray(rng.normal(size=(len(ids), n)).astype(np.float32))
+    return jnp.asarray(wp), jnp.asarray(ids), G
+
+
+def test_batched_sparse_apply_scatters_into_donated_rows():
+    """The sparse ``apply_rows`` program is one flat scatter per batch
+    row straight into its donated ``wp`` (no vmapped copy of the rows),
+    and equals the serial ``apply_update`` of each row, bit for bit."""
+    import re
+
+    import jax
+    from repro.core import server as ps
+    from repro.core.sparsify import SparseLeaf
+
+    wp, ids, G = _apply_batch(sparse=True)
+    apply_rows = async_sim.make_batched_apply()
+    text = apply_rows.lower(wp, ids, G).as_text()
+    assert "tf.aliasing_output = 0" in text
+    scatters = re.findall(r"\}\) : \((tensor<[^,]*), tensor<[^,]*xi32>",
+                          text)
+    assert scatters == [f"tensor<{wp.shape[1]}xf32>"] * len(ids), scatters
+    assert "gather" not in text
+
+    expect = np.asarray(wp).copy()
+    for b, r in enumerate(np.asarray(ids)):
+        expect[r] = np.asarray(ps.apply_update(
+            wp[r], SparseLeaf(G.values[b], G.indices[b], G.size)))
+    out = np.asarray(apply_rows(jax.numpy.copy(wp), ids, G))
+    np.testing.assert_array_equal(out.view(np.uint32),
+                                  expect.view(np.uint32))
+
+
+def test_batched_dense_apply_lowering_unchanged():
+    """A dense batch (ASGD) keeps the vmapped gather-apply-set program:
+    its lowering is text-equal to the expression written out here."""
+    import jax
+    from repro.core import server as ps
+
+    wp, ids, G = _apply_batch(sparse=False)
+
+    def apply_rows(wp, ids, G):
+        return wp.at[ids].set(jax.vmap(ps.apply_update)(wp[ids], G))
+
+    want = jax.jit(apply_rows, donate_argnums=(0,)).lower(wp, ids, G)
+    got = async_sim.make_batched_apply().lower(wp, ids, G)
+    assert got.as_text() == want.as_text()

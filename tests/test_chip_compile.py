@@ -15,6 +15,7 @@ loads the TPU library.  The kernel branch is chosen explicitly
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.kernels import ops, wire_pack
@@ -107,3 +108,30 @@ def test_route_by_shard_batch_compiles(one_chip):
         i, v, bounds=jnp.asarray(bounds, jnp.int32), n_shards=4, cap=K),
         one_chip, ((16, K), jnp.int32), ((16, K), jnp.float32),
         kernel=False)
+
+
+@pytest.mark.parametrize("name", ["scatter_add", "scatter_add_row",
+                                  "scatter_add_rows"])
+def test_arena_scatter_add_updates_donated_buffer_in_place(one_chip, name):
+    """The arena scatter-adds at the 32-worker cell's shapes: the donated
+    buffer is the output (aliased), and the program's scratch holds at
+    most a row per batch lane, never a copy of the (32, arena) buffer
+    (which a 2-D XLA scatter costs: the compiler relays it out to a flat
+    layout and back)."""
+    w, b = 32, 16
+    shapes = {
+        "scatter_add": [((ARENA,), jnp.float32), ((K,), jnp.int32),
+                        ((K,), jnp.float32)],
+        "scatter_add_row": [((w, ARENA), jnp.float32), ((), jnp.int32),
+                            ((K,), jnp.int32), ((K,), jnp.float32)],
+        "scatter_add_rows": [((w, ARENA), jnp.float32), ((b,), jnp.int32),
+                             ((b, K), jnp.int32), ((b, K), jnp.float32)],
+    }[name]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(getattr(ops, name), donate_argnums=(0,)).lower(
+        *args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # the aliased buffer, padded to the chip's tiles
+    assert mem.alias_size_in_bytes >= 4 * int(np.prod(shapes[0][0]))
+    assert mem.temp_size_in_bytes < 2 * 4 * ARENA, mem.temp_size_in_bytes

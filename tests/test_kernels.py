@@ -192,6 +192,69 @@ def test_scatter_apply_keeps_negative_zero():
     assert float(out[7]) == 1.5
 
 
+def _arena_scatter_case(name):
+    """``(fn, args, touched_rows)`` for one arena scatter-add: a buffer of
+    5 rows x 3000 values with -0.0 in every 7th lane, distinct indices
+    per row (a flat-arena message carries no duplicates)."""
+    rng = np.random.default_rng(11)
+    w, n, k = 5, 3000, 40
+    dense = rng.normal(size=(w, n)).astype(np.float32)
+    dense[:, ::7] = -0.0
+    rows = np.asarray([3, 0, 4], np.int32)
+    idx = np.stack([rng.choice(n, k, replace=False)
+                    for _ in rows]).astype(np.int32)
+    vals = rng.normal(size=(len(rows), k)).astype(np.float32)
+    if name == "scatter_add":
+        return ops.scatter_add, (dense[0], idx[0], vals[0]), None
+    if name == "scatter_add_row":
+        return (ops.scatter_add_row, (dense, rows[0], idx[0], vals[0]),
+                rows[:1])
+    return ops.scatter_add_rows, (dense, rows, idx, vals), rows
+
+
+@pytest.mark.parametrize("name", ["scatter_add", "scatter_add_row",
+                                  "scatter_add_rows"])
+def test_arena_scatter_add_is_one_flat_scatter_per_row(name):
+    """Each arena scatter-add lowers to one XLA scatter per touched row,
+    each into a flat ``(total,)`` row: no Pallas call, no gather, no 2-D
+    scatter (which the TPU compiler lowers by copying the whole buffer),
+    and at most one row-sized write-back per row.  Every backend runs
+    this one path, so the check covers the chip's program too.  The
+    result equals ``ref.scatter_accumulate_ref`` row by row, bit for bit,
+    -0.0 in untouched lanes included."""
+    import re
+
+    fn, args, rows = _arena_scatter_case(name)
+    dense = args[0]
+    n = dense.shape[-1]
+    n_rows = 1 if rows is None else len(rows)
+    text = jax.jit(fn, donate_argnums=(0,)).lower(
+        *map(jnp.asarray, args)).as_text()
+    scatters = re.findall(r"stablehlo\.scatter.*?\n.*?\}\) : \((tensor<[^,]*)",
+                          text, re.S)
+    assert scatters == [f"tensor<{n}xf32>"] * n_rows, scatters
+    assert "custom_call" not in text
+    assert "gather" not in text
+    writes = re.findall(r"stablehlo\.dynamic_update_slice [^\n]*: "
+                        r"\(tensor<[^>]*>, (tensor<[^>]*>)", text)
+    assert writes == ([] if rows is None else [f"tensor<1x{n}xf32>"] * n_rows)
+
+    out = np.asarray(jax.jit(fn)(*map(jnp.asarray, args)))
+    if rows is None:
+        expect = np.asarray(ref.scatter_accumulate_ref(
+            jnp.asarray(dense), jnp.asarray(args[1]), jnp.asarray(args[2])))
+    else:
+        idx, vals = (np.atleast_2d(a) for a in args[2:])
+        expect = dense.copy()
+        for b, r in enumerate(rows):
+            expect[r] = np.asarray(ref.scatter_accumulate_ref(
+                jnp.asarray(dense[r]), jnp.asarray(idx[b]),
+                jnp.asarray(vals[b])))
+    np.testing.assert_array_equal(out.view(np.uint32),
+                                  expect.view(np.uint32))
+    assert np.signbit(out[..., ::7]).any()
+
+
 def test_block_topk_full_block_sweep():
     """r == BLOCK: one loop of BLOCK selection sweeps sorts every block
     by magnitude, matching the reference exactly."""
